@@ -51,7 +51,10 @@ def _block(r):
 
 
 def run_subprocess(code: str, n_devices: int = 8, timeout: int = 560) -> str:
+    """Run ``code`` in a child with ``n_devices`` simulated CPU devices
+    (pinned to the CPU backend: a parent on a chip host may hold it)."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,

@@ -151,6 +151,12 @@ def _np_bytes(shape, dtype) -> int:
     return n * np.dtype(dtype).itemsize
 
 
+def _block_dim(b) -> int:
+    """One grid_mapping block dim as an int: ``Blocked``/``Element``/
+    ``BoundedSlice`` carry ``block_size``; ``Squeezed`` is one row."""
+    return b if isinstance(b, int) else int(getattr(b, "block_size", 1))
+
+
 def pallas_launches(jaxpr) -> List[PallasLaunch]:
     """Extract every pallas_call in a jaxpr with its grid and per-operand
     block footprint, read from the REAL lowered grid_mapping (not a
@@ -165,8 +171,8 @@ def pallas_launches(jaxpr) -> List[PallasLaunch]:
         buffers: List[BlockBuffer] = []
         n_in = len(eqn.invars)
         for i, bm in enumerate(gm.block_mappings):
-            sd = bm.array_shape_dtype
-            block = tuple(int(b) for b in bm.block_shape)
+            sd = bm.array_aval
+            block = tuple(_block_dim(b) for b in bm.block_shape)
             varying = tuple(sd.shape) != block
             tag = f"in{i}" if i < n_in else f"out{i - n_in}"
             buffers.append(BlockBuffer(

@@ -12,6 +12,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding
 
 
 def _flatten(tree: Any) -> Tuple[Dict[str, np.ndarray], Dict[str, str]]:
@@ -72,8 +73,12 @@ def restore_checkpoint(ckpt_dir: str, template: Any,
         arr = data[key]
         assert arr.shape == leaf.shape, (key, arr.shape, leaf.shape)
         if meta.get("dtypes", {}).get(key) == "bfloat16":
-            val = jax.numpy.asarray(arr).view(jax.numpy.bfloat16)
-        else:
-            val = jax.numpy.asarray(arr)
-        leaves.append(val.astype(leaf.dtype))
+            arr = arr.view(jax.numpy.bfloat16)
+        arr = arr.astype(leaf.dtype)
+        # a mesh-sharded leaf goes straight to its shards from host, never
+        # through one device
+        sharding = getattr(leaf, "sharding", None)
+        leaves.append(jax.device_put(arr, sharding)
+                      if isinstance(sharding, NamedSharding)
+                      else jax.numpy.asarray(arr))
     return jax.tree_util.tree_unflatten(treedef, leaves), meta

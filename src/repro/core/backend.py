@@ -191,20 +191,20 @@ def pallas_fused_backend(params: Params, x: jax.Array, cfg: ModelConfig,
     (E, C, d) buffer and its two extra HBM roundtrips are gone, and the
     five per-layer kernel launches collapse to one.
 
-    Falls back to the unfused `pallas` path when a real mesh is active
-    (expert parallelism needs the materialized buffer on the wire) or when
-    the comm substrate is compressed (the quant->dequant payload transform
-    applies to that buffer); ep=1 dense/hierarchical wires are identity,
-    so skipping them changes nothing (DESIGN.md §10)."""
+    Raises under a real mesh (expert parallelism needs the materialized
+    buffer on the wire) or a compressed substrate (the quant->dequant
+    payload transform applies to that buffer) rather than turn into the
+    `pallas` path: choose `pallas` there. ep=1 dense/hierarchical wires are
+    identity, so skipping them changes nothing (DESIGN.md §10)."""
     from repro.core.moe import (_local_adjust, _local_aux, _routed_aux,
                                 _select_branch, _shard_rng, _zero_aux)
     from repro.kernels import ops as K
 
     moe = cfg.moe
     if (ctx is not None and ctx.active) or moe.comm.compressed:
-        return pallas_backend(params, x, cfg, ctx, rng=rng, decision=decision,
-                              is_training=is_training, token_ids=token_ids,
-                              token_valid=token_valid, interpret=interpret)
+        raise ValueError(
+            "backend 'pallas_fused' has no expert-parallel or compressed-"
+            "wire path; use backend 'pallas'")
 
     from repro.comm import CommEnv, make_transport
 

@@ -426,6 +426,9 @@ def moe_sharded(params: Params, x: jax.Array, cfg: ModelConfig,
     traced = static_dec is None and decision is not None
 
     def body(wr, experts, x_loc, rng_, dec, tok_loc, tv_loc):
+        # the placeholder key below stands for "no rng": no router jitter,
+        # as in the oracle and pallas backends
+        rng_ = None if rng is None else rng_
         B_loc, L, d = x_loc.shape
         xf = x_loc.reshape(B_loc * L, d)
         tf = None if tok_loc is None else tok_loc.reshape(-1)
@@ -483,18 +486,9 @@ def moe_sharded(params: Params, x: jax.Array, cfg: ModelConfig,
         tv_loc = ops[i] if token_valid is not None else None
         return body(wr, experts, x_loc, rng_, dec, tok_loc, tv_loc)
 
-    fn = _shard_map(wrapper, mesh, tuple(in_specs), (x_spec, P()))
+    fn = jax.shard_map(wrapper, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=(x_spec, P()), check_vma=False)
     return fn(*args)
-
-
-def _shard_map(fn, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (experimental module pre-0.6)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
 
 
 def moe_apply(params: Params, x: jax.Array, cfg: ModelConfig,

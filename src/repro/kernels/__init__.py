@@ -3,7 +3,7 @@
 Each kernel has a pure-jnp oracle in ref.py; ops.py holds the jit'd
 wrappers and the routing-table builders. interpret mode is auto-detected
 per platform (platform.default_interpret, DESIGN.md §6): interpreter on
-CPU/GPU for correctness, compiled with MXU-aligned BlockSpecs on TPU.
+CPU for correctness, compiled with MXU-aligned BlockSpecs on TPU.
 """
 from repro.kernels import ops, ref
 from repro.kernels.flash_decode import flash_decode, flash_decode_paged

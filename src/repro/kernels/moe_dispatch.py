@@ -4,15 +4,20 @@ TPU adaptation of the scatter/gather around the all-to-all: instead of a
 data-dependent scatter (expensive on TPU), routing is precomputed into a
 slot->token map and the kernels become PURE GATHERS whose BlockSpec
 index_maps read the prefetched scalar routing tables — each grid step DMAs
-exactly one (1, d)-row from HBM to VMEM. This is the megablocks-style
+exactly one row from HBM to VMEM. This is the megablocks-style
 TPU-idiomatic form: the MXU never sees routing logic, and the gather rides
 the scalar-prefetch pipeline.
+
+Rows travel as ``(rows, 1, d)`` views: Mosaic tiles the last two block
+dims by (8, 128) unless a block dim equals the array dim, so a one-row
+block of a ``(rows, d)`` array is refused while the ``(1, 1, bd)`` block
+of the 3-D view is legal (its second-minor dim equals the array's 1).
 
   dispatch: buf[s] = x[slot_token[s]] * valid[s]       (S = E*C slots)
   combine : y[t]  = sum_k w[t,k] * buf[token_slot[t,k]]
 
 ``interpret=None`` (default) auto-detects the platform (DESIGN.md §6):
-compiled on TPU, interpreter elsewhere. The slot maps consumed here are
+compiled on TPU, interpreter on CPU. The slot maps consumed here are
 built once per step by ``repro.kernels.ops.routing_tables`` and shared by
 both gathers.
 
@@ -44,29 +49,39 @@ def _float0_like(a: jax.Array):
 # dispatch
 # ---------------------------------------------------------------------------
 
+def _row_block(d: int, bd: int) -> int:
+    """Lane-dim block of a row: a multiple of 128 dividing ``d``, else the
+    whole row (both satisfy Mosaic's tiling rule)."""
+    b = fit_block(d, bd)
+    return b if b % 128 == 0 else d
+
+
 def _dispatch_kernel(idx_ref, valid_ref, x_ref, o_ref):
     s = pl.program_id(0)
-    o_ref[0] = jnp.where(valid_ref[s] > 0, x_ref[0], 0).astype(o_ref.dtype)
+    o_ref[...] = jnp.where(valid_ref[s] > 0, x_ref[...], 0).astype(o_ref.dtype)
 
 
 def _dispatch_impl(x, idx, valid, bd, interpret):
     t, d = x.shape
     s = idx.shape[0]
-    bd = fit_block(d, bd)
+    bd = _row_block(d, bd)
     grid = (s, d // bd)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _dispatch_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, bd), lambda si, dj, idx, val: (idx[si], dj)),
+                pl.BlockSpec((1, 1, bd),
+                             lambda si, dj, idx, val: (idx[si], 0, dj)),
             ],
-            out_specs=pl.BlockSpec((1, bd), lambda si, dj, idx, val: (si, dj)),
+            out_specs=pl.BlockSpec((1, 1, bd),
+                                   lambda si, dj, idx, val: (si, 0, dj)),
         ),
-        out_shape=jax.ShapeDtypeStruct((s, d), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, 1, d), x.dtype),
         interpret=interpret,
-    )(idx, valid, x)
+    )(idx, valid, x.reshape(t, 1, d))
+    return out.reshape(s, d)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -118,35 +133,40 @@ def _make_combine_kernel(k: int):
         # refs: k buffer views + o_ref
         o_ref = refs[-1]
         t = pl.program_id(0)
-        acc = jnp.zeros(o_ref.shape[-1:], jnp.float32)
+        acc = jnp.zeros(o_ref.shape, jnp.float32)
         for kk in range(k):
-            acc = acc + w_ref[t, kk] * refs[kk][0].astype(jnp.float32)
-        o_ref[0] = acc.astype(o_ref.dtype)
+            acc = acc + w_ref[t * k + kk] * refs[kk][...].astype(jnp.float32)
+        o_ref[...] = acc.astype(o_ref.dtype)
     return kernel
 
 
 def _combine_impl(buf, slots, w, bd, interpret):
     s, d = buf.shape
     t, k = slots.shape
-    bd = fit_block(d, bd)
+    bd = _row_block(d, bd)
     grid = (t, d // bd)
     in_specs = [
-        pl.BlockSpec((1, bd),
+        pl.BlockSpec((1, 1, bd),
                      functools.partial(
-                         lambda kk, ti, dj, slot, w_: (slot[ti, kk], dj), kk))
+                         lambda kk, ti, dj, slot, w_: (slot[ti * k + kk], 0,
+                                                       dj),
+                         kk))
         for kk in range(k)
     ]
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _make_combine_kernel(k),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, bd), lambda ti, dj, slot, w_: (ti, dj)),
+            out_specs=pl.BlockSpec((1, 1, bd),
+                                   lambda ti, dj, slot, w_: (ti, 0, dj)),
         ),
-        out_shape=jax.ShapeDtypeStruct((t, d), buf.dtype),
+        out_shape=jax.ShapeDtypeStruct((t, 1, d), buf.dtype),
         interpret=interpret,
-    )(slots, w, *([buf] * k))
+    # flat (T*k,) tables: SMEM pads a 2-D table's minor dim to 128 words
+    )(slots.reshape(-1), w.reshape(-1), *([buf.reshape(s, 1, d)] * k))
+    return out.reshape(t, d)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

@@ -62,27 +62,57 @@ def _act_f32(act: str):
 # kernel
 # ---------------------------------------------------------------------------
 
-def _make_kernel(gated: bool, act: str):
+def _sublanes(dtype) -> int:
+    """Rows per (sublane x lane) tile: 8 for 32-bit, 16 for bf16."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _row_block(ref, r):
+    """The tile-aligned row block of ``ref`` holding row ``r`` and a mask
+    selecting that row: Mosaic loads and stores only at sublane offsets
+    it can prove tile-aligned, so one dynamic row is read and written
+    through its aligned block."""
+    sub = _sublanes(ref.dtype)
+    base = pl.multiple_of((r // sub) * sub, sub)
+    sel = jax.lax.broadcasted_iota(jnp.int32, (sub, 1), 0) == r - base
+    return pl.ds(base, sub), sel
+
+
+def _make_kernel(gated: bool, act: str, c: int):
     actf = _act_f32(act)
 
-    def kernel(x_ref, *refs):
-        # refs: w_in, [w_gate], w_out, slot_token, wslot, o_ref, acc_ref
+    def kernel(st_ref, ws_ref, x_ref, *refs):
+        # st_ref/ws_ref: flat (E*C,) slot-token / slot-weight tables in SMEM
+        # refs: w_in, [w_gate], w_out, o_ref, acc_ref, rows_ref, out_ref
         w_in_ref = refs[0]
         w_gate_ref = refs[1] if gated else None
         w_out_ref = refs[2] if gated else refs[1]
-        st_ref, ws_ref = refs[-4], refs[-3]
-        o_ref, acc_ref = refs[-2], refs[-1]
+        o_ref, acc_ref, rows_ref, out_ref = refs[-4:]
         e_i = pl.program_id(0)
         j = pl.program_id(1)
+        t = o_ref.shape[0]
 
         @pl.when((e_i == 0) & (j == 0))
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        t = x_ref.shape[0]
-        idx = jnp.clip(st_ref[0], 0, t - 1)                    # (C,)
-        rows = jnp.take(x_ref[...], idx, axis=0)               # gather (C, d)
-        rows = rows.astype(jnp.float32)
+        def token(cc):
+            return jnp.clip(st_ref[e_i * c + cc], 0, t - 1)
+
+        def gather(cc, carry):                                 # (C, d) rows
+            xs, xsel = _row_block(x_ref, token(cc))
+            row = jnp.sum(jnp.where(xsel, x_ref[xs, :].astype(jnp.float32),
+                                    0.0), axis=0, keepdims=True)
+            rs, rsel = _row_block(rows_ref, cc)
+            rows_ref[rs, :] = jnp.where(rsel, row, rows_ref[rs, :])
+            return carry
+
+        jax.lax.fori_loop(0, c, gather, 0)
+        rows = rows_ref[...]
         h = jnp.dot(rows, w_in_ref[0].astype(jnp.float32),
                     preferred_element_type=jnp.float32)        # (C, bf)
         if gated:
@@ -91,17 +121,35 @@ def _make_kernel(gated: bool, act: str):
             h = actf(g) * h
         else:
             h = actf(h)
-        out = jnp.dot(h, w_out_ref[0].astype(jnp.float32),
-                      preferred_element_type=jnp.float32)      # (C, d)
-        contrib = ws_ref[0][:, None] * out
-        acc_ref[...] = acc_ref[...].at[idx].add(contrib)       # scatter (T, d)
+        out_ref[...] = jnp.dot(h, w_out_ref[0].astype(jnp.float32),
+                               preferred_element_type=jnp.float32)  # (C, d)
+
+        def scatter(cc, carry):                                # (T, d) acc
+            os_, osel = _row_block(out_ref, cc)
+            row = jnp.sum(jnp.where(osel, out_ref[os_, :], 0.0), axis=0,
+                          keepdims=True) * ws_ref[e_i * c + cc]
+            acs, asel = _row_block(acc_ref, token(cc))
+            acc_ref[acs, :] += jnp.where(asel, row, 0.0)
+            return carry
+
+        jax.lax.fori_loop(0, c, scatter, 0)
 
         @pl.when((e_i == pl.num_programs(0) - 1)
                  & (j == pl.num_programs(1) - 1))
         def _done():
-            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+            o_ref[...] = acc_ref[:t].astype(o_ref.dtype)
 
     return kernel
+
+
+def _vmem_limit(tp: int, d: int, bf: int, cp: int, n_w: int, itemsize: int
+                ) -> int:
+    """Scoped-VMEM request for the resident (T, d) blocks: double-buffered
+    x and output, the f32 accumulator, double-buffered weight blocks and
+    the f32 (C, d) row/output scratch, plus 25% headroom."""
+    need = (2 * tp * d * itemsize * 2 + tp * d * 4
+            + 2 * n_w * d * bf * itemsize + 2 * cp * d * 4)
+    return int(need * 1.25) + (4 << 20)
 
 
 def _fused_impl(x, w_in, w_gate, w_out, wcomb, slot_token, token_slot, act,
@@ -117,27 +165,41 @@ def _fused_impl(x, w_in, w_gate, w_out, wcomb, slot_token, token_slot, act,
     # dropped entries scatter-add their (clipped) index with weight 0
     wslot = jnp.zeros((s,), jnp.float32).at[token_slot.reshape(-1)].add(
         wcomb.reshape(-1))
+    # rows are read and written through whole tiles (_row_block), so the
+    # resident x and the scratch pad their row counts to the tile height
+    tp = _round_up(t, _sublanes(x.dtype))
+    cp = _round_up(c, 8)
+    xp = jnp.pad(x, ((0, tp - t), (0, 0))) if tp != t else x
 
-    in_specs = [pl.BlockSpec((t, d), lambda e_, j: (0, 0)),
-                pl.BlockSpec((1, d, bf), lambda e_, j: (e_, 0, j))]
-    operands = [x, w_in]
+    in_specs = [pl.BlockSpec((tp, d), lambda e_, j, st, ws: (0, 0)),
+                pl.BlockSpec((1, d, bf), lambda e_, j, st, ws: (e_, 0, j))]
+    operands = [xp, w_in]
     if gated:
-        in_specs += [pl.BlockSpec((1, d, bf), lambda e_, j: (e_, 0, j))]
+        in_specs += [pl.BlockSpec((1, d, bf),
+                                  lambda e_, j, st, ws: (e_, 0, j))]
         operands += [w_gate]
-    in_specs += [pl.BlockSpec((1, bf, d), lambda e_, j: (e_, j, 0)),
-                 pl.BlockSpec((1, c), lambda e_, j: (e_, 0)),
-                 pl.BlockSpec((1, c), lambda e_, j: (e_, 0))]
-    operands += [w_out, slot_token.reshape(e, c), wslot.reshape(e, c)]
+    in_specs += [pl.BlockSpec((1, bf, d), lambda e_, j, st, ws: (e_, j, 0))]
+    operands += [w_out]
+    itemsize = jnp.dtype(x.dtype).itemsize
 
     return pl.pallas_call(
-        _make_kernel(gated, act),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((t, d), lambda e_, j: (0, 0)),
-        scratch_shapes=[pltpu.VMEM((t, d), jnp.float32)],
+        _make_kernel(gated, act, c),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((t, d), lambda e_, j, st, ws: (0, 0)),
+            scratch_shapes=[pltpu.VMEM((_round_up(t, 8), d), jnp.float32),
+                            pltpu.VMEM((cp, d), jnp.float32),
+                            pltpu.VMEM((cp, d), jnp.float32)],
+        ),
         out_shape=jax.ShapeDtypeStruct((t, d), x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit(tp, d, bf, cp, 3 if gated else 2,
+                                         itemsize)),
         interpret=interpret,
-    )(*operands)
+    )(slot_token, wslot, *operands)
 
 
 def _ref_forward(x, w_in, w_gate, w_out, wcomb, slot_token, slot_valid,

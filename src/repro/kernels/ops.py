@@ -1,7 +1,7 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 ``interpret=None`` (the default) auto-detects the platform (DESIGN.md §6):
-kernels compile on TPU and run under the Pallas interpreter elsewhere.
+kernels compile on TPU and run under the Pallas interpreter on CPU.
 Routing-table construction (slot maps) lives here: ``routing_tables`` turns
 the router's DispatchInfo into the gather form the kernels consume, ONCE
 per step — both the dispatch and the combine gather reuse the same tables.
@@ -24,7 +24,7 @@ from repro.kernels.platform import (default_interpret, force_interpret,
                                     resolve_interpret)
 
 # Global switch: when True the MoE layer routes its dispatch/FFN/combine
-# through the Pallas kernels (interpret mode off-TPU). Flip with
+# through the Pallas kernels (interpret mode on CPU). Flip with
 # use_kernels(); the `pallas` execution backend (core/backend.py) uses the
 # kernels unconditionally.
 KERNELS_ENABLED = False

@@ -1,10 +1,10 @@
 """Platform detection for the Pallas kernels (DESIGN.md §6).
 
 Every kernel wrapper takes ``interpret: bool | None``. ``None`` (the
-default) means *auto*: compile for real on TPU, fall back to the Pallas
-interpreter everywhere else (CPU containers, GPU hosts). This replaces the
-old hard-coded ``interpret=True`` so the same call sites are
-correctness-checked off-TPU and compiled on-TPU with no code change.
+default) means *auto*: compile for real on TPU, run the Pallas
+interpreter on the CPU backend (where the differential tests run), and
+refuse every other backend — a GPU host running the TPU kernels in the
+interpreter would report interpreter numbers under a device's name.
 
 ``force_interpret`` exists for tests and benchmarks that want to pin the
 mode regardless of platform (e.g. measuring interpreter overhead).
@@ -21,11 +21,18 @@ _FORCED: Optional[bool] = None
 
 
 def default_interpret() -> bool:
-    """True unless running on a real TPU (Pallas TPU kernels compile only
-    there; interpret mode is the portable fallback)."""
+    """False on TPU (the kernels compile), True on CPU (interpreter);
+    any other backend raises."""
     if _FORCED is not None:
         return _FORCED
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels compile for TPU and are interpreted on CPU; "
+        f"backend {backend!r} is neither")
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:

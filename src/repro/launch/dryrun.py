@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: prove every (architecture x input-shape x mesh)
 combination lowers AND compiles on the production meshes, and extract the
 roofline inputs (FLOPs, bytes, collective traffic) from the compiled
@@ -11,7 +8,16 @@ artifact.
 
 Artifacts: benchmarks/artifacts/dryrun/<arch>__<shape>__<mesh>[__tag].json
 """
-import argparse
+import os
+
+# 512 simulated devices for the production meshes, merged into the
+# caller's XLA_FLAGS (a caller-set device count wins)
+_DEVICES_FLAG = "--xla_force_host_platform_device_count"
+if _DEVICES_FLAG not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + f" {_DEVICES_FLAG}=512").strip()
+
+import argparse  # noqa: E402
 import json
 import re
 from typing import Any, Dict

@@ -84,6 +84,26 @@ def apply(environ: Optional[Dict[str, str]] = None) -> Dict[str, str]:
     return delta
 
 
+# fixed in-checkout compile-cache path (listed in .gitignore): JAX keys
+# cache entries by path too, so a moving directory would never hit
+COMPILE_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache"))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache before the first compile
+    and return its directory. ``JAX_COMPILATION_CACHE_DIR``, when set, is
+    read by JAX itself and nothing else is set; otherwise the cache goes
+    to the fixed ``<checkout>/.jax_cache``."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
+
 def _sh_quote(s: str) -> str:
     return "'" + s.replace("'", "'\\''") + "'"
 

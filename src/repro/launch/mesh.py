@@ -6,10 +6,8 @@ import jax
 
 
 def _mk(shape, axes):
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)   # older jax: axes are Auto by default
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,10 +24,6 @@ def make_mesh(shape, axes):
 
 
 def abstract_mesh(shape, axes):
-    """AbstractMesh across jax versions: no devices needed, spec-validity
-    checks only (used by tests against the production mesh shapes)."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))        # >= 0.5 API
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))          # 0.4.x API
+    """AbstractMesh: no devices needed, spec-validity checks only (used by
+    tests against the production mesh shapes)."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))

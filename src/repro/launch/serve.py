@@ -36,6 +36,7 @@ import jax
 import numpy as np
 
 from repro.configs import PagedKVConfig, get_config, reduced
+from repro.launch.env import enable_compile_cache
 from repro.models import init_model
 from repro.obs import (Histogram, MetricsRegistry, Tracer, monotonic,
                        set_tracer)
@@ -67,9 +68,8 @@ def synth_trace(cfg, key, n: int, rate: float, buckets, max_new: int):
     """Synthetic request trace: Poisson arrivals (exponential gaps at
     ``rate`` req/s), prompt lengths uniform over [2, max bucket], token
     budgets uniform over [2, max_new]."""
-    rs = np.random.RandomState(np.asarray(
-        jax.random.key_data(key) if hasattr(jax.random, "key_data")
-        else key)[-1] & 0x7FFFFFFF)
+    rs = np.random.RandomState(
+        np.asarray(jax.random.key_data(key))[-1] & 0x7FFFFFFF)
     gaps = rs.exponential(1.0 / rate, size=n)
     arrivals = np.cumsum(gaps) - gaps[0]
     reqs = []
@@ -303,6 +303,7 @@ def main():
                          "(.prom/.txt = Prometheus text, else JSON)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     tracer = Tracer(enabled=bool(args.trace_out))
     set_tracer(tracer)
 

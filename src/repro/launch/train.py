@@ -24,6 +24,7 @@ from repro.configs import get_config, reduced
 from repro.configs.base import TrainConfig
 from repro.core.moe import ParallelContext
 from repro.data import MTTaskConfig, MultilingualMT, LMTaskConfig, SyntheticLM
+from repro.launch.env import enable_compile_cache
 from repro.launch.mesh import make_mesh
 from repro.metrics import corpus_bleu, strip_special
 from repro.obs import MetricsRegistry, Tracer, router_health, set_tracer
@@ -138,7 +139,9 @@ def main():
                          "(TensorBoard/Perfetto logdir)")
     args = ap.parse_args()
 
-    tracer = Tracer(enabled=bool(args.trace_out))
+    enable_compile_cache()
+    # spans also name the chunks on the device timeline of --jax-profile
+    tracer = Tracer(enabled=bool(args.trace_out or args.jax_profile))
     set_tracer(tracer)
 
     cfg = get_config(args.arch)
@@ -193,10 +196,7 @@ def main():
         # data stream (batch_fn) and the Gating-Dropout consensus PRNG
         # (seed, step) pick up exactly where the checkpointed run left off
         print(f"resumed {args.ckpt_dir} @ step {trainer.restore()}")
-    if args.jax_profile:
-        with tracer.profile_window(args.jax_profile):
-            state, history = trainer.run()
-    else:
+    with tracer.profile_window(args.jax_profile):
         state, history = trainer.run()
     if args.ckpt_dir:
         print(f"checkpoint -> {args.ckpt_dir}")

@@ -81,7 +81,9 @@ def init_model(key: jax.Array, cfg: ModelConfig) -> Params:
 # ---------------------------------------------------------------------------
 
 def _encode(params: Params, batch: Dict, cfg: ModelConfig, ctx, *,
-            rng, decision, is_training):
+            rng, decision, is_training, enc_embed=None):
+    """``enc_embed``: the encoder tokens' embedding rows when the caller
+    already looked them up (``model_apply``)."""
     enc_segs = T.layer_plan(cfg, encoder=True)
     if "frames" in batch:                      # audio stub frontend output
         x = batch["frames"].astype(cfg.dtype)
@@ -89,7 +91,9 @@ def _encode(params: Params, batch: Dict, cfg: ModelConfig, ctx, *,
         tok = None
     else:
         tok = batch["enc_tokens"]
-        x = L.embed_apply(params["embed"], tok).astype(cfg.dtype)
+        if enc_embed is None:
+            enc_embed = L.embed_apply(params["embed"], tok)
+        x = enc_embed.astype(cfg.dtype)
         x = x + L.sinusoidal_pos(x.shape[1], cfg.d_model, x.dtype)[None]
     x, _, aux = T.apply_stack(params["encoder"], enc_segs, x, cfg, ctx,
                               mode="train", rng=rng, decision=decision,
@@ -98,11 +102,11 @@ def _encode(params: Params, batch: Dict, cfg: ModelConfig, ctx, *,
 
 
 def _cross_source(params: Params, batch: Dict, cfg: ModelConfig, ctx, *,
-                  rng, decision, is_training):
+                  rng, decision, is_training, enc_embed=None):
     """Returns (cross_src, aux) for families that cross-attend."""
     if cfg.encdec is not None:
         return _encode(params, batch, cfg, ctx, rng=rng, decision=decision,
-                       is_training=is_training)
+                       is_training=is_training, enc_embed=enc_embed)
     if cfg.vlm is not None:
         img = batch["img_embeds"].astype(cfg.dtype)
         return (img.astype(params["img_proj"].dtype) @ params["img_proj"]
@@ -136,8 +140,19 @@ def model_apply(params: Params, batch: Dict, cfg: ModelConfig,
     (B, L, V) f32 logits tensor never materializes)."""
     tokens = batch["tokens"]
     segs = T.layer_plan(cfg)
-    x = L.embed_apply(params["embed"], tokens).astype(cfg.dtype)
-    x = _constrain(x, ctx, ("dp", None, None))
+    enc_embed = None
+    if cfg.encdec is not None and "enc_tokens" in batch:
+        # both streams share the table: look them up in ONE gather. Two
+        # gathers give two gradient scatters that XLA merges into one
+        # concatenated scatter, which the TPU partitioner re-shards with
+        # all-to-alls, in the Gate-Drop step too
+        n_enc = batch["enc_tokens"].shape[1]
+        both = L.embed_apply(params["embed"], jnp.concatenate(
+            [batch["enc_tokens"], tokens], axis=1))
+        enc_embed, x = both[:, :n_enc], both[:, n_enc:]
+    else:
+        x = L.embed_apply(params["embed"], tokens)
+    x = _constrain(x.astype(cfg.dtype), ctx, ("dp", None, None))
     n_meta = 0
     if cfg.hybrid is not None:
         n_meta = cfg.hybrid.n_meta_tokens
@@ -146,7 +161,8 @@ def model_apply(params: Params, batch: Dict, cfg: ModelConfig,
         x = jnp.concatenate([meta, x], axis=1)
     cross_src, enc_aux = _cross_source(params, batch, cfg, ctx, rng=rng,
                                        decision=decision,
-                                       is_training=is_training)
+                                       is_training=is_training,
+                                       enc_embed=enc_embed)
     x, _, aux = T.apply_stack(params["decoder"], segs, x, cfg, ctx,
                               mode="train", rng=rng, decision=decision,
                               is_training=is_training, cross_src=cross_src,

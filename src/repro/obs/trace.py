@@ -137,17 +137,14 @@ class Tracer:
         ``jax.profiler`` window, free no-op otherwise."""
         if not self.enabled:
             return _NULL
-        try:
-            import jax.profiler
-            return jax.profiler.TraceAnnotation(name)
-        except Exception:   # profiler unavailable on exotic builds
-            return _NULL
+        import jax.profiler
+        return jax.profiler.TraceAnnotation(name)
 
     def profile_window(self, logdir: Optional[str]):
-        """Optional ``jax.profiler.trace`` window writing a TensorBoard-
-        loadable device profile under ``logdir`` alongside this tracer's
-        host spans."""
-        if not self.enabled or not logdir:
+        """``jax.profiler.trace`` window writing a TensorBoard-loadable
+        device profile under ``logdir`` (no-op when ``logdir`` is empty).
+        Independent of ``enabled``: a device profile needs no host spans."""
+        if not logdir:
             return _NULL
         import jax.profiler
         return jax.profiler.trace(logdir)

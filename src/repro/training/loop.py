@@ -52,6 +52,19 @@ from repro.training.steps import init_train_state, make_train_step
 TOKEN_KEYS = ("tokens", "enc_tokens")
 
 
+def train_state_sharding(cfg: ModelConfig, tc: TrainConfig,
+                         ctx: Optional[ParallelContext] = None) -> Any:
+    """The train state's NamedShardings on ``ctx``'s mesh by the rules of
+    DESIGN.md §4 (``parallel/sharding.py::state_specs``): expert weights
+    and their Adam moments split over the expert axis. None off a mesh."""
+    if ctx is None or not ctx.active:
+        return None
+    from repro.parallel.sharding import state_specs, to_shardings
+    shape = jax.eval_shape(lambda: init_train_state(
+        init_model(jax.random.PRNGKey(tc.seed), cfg), tc))
+    return to_shardings(ctx.mesh, state_specs(cfg, ctx, shape))
+
+
 def make_chunk_step(cfg: ModelConfig, tc: TrainConfig,
                     ctx: Optional[ParallelContext] = None,
                     *, jit: bool = True) -> Callable:
@@ -66,6 +79,8 @@ def make_chunk_step(cfg: ModelConfig, tc: TrainConfig,
       bool -> host_cond run: baked in as a static argument; jit caches
               one executable per (decision, K). With the decision static
               the dropped executable contains no all-to-all at all.
+    On a mesh the returned state is pinned to ``train_state_sharding`` so
+    it keeps its layout chunk to chunk.
     """
     step_fn = make_train_step(cfg, tc, ctx, jit=False)
     gd = cfg.moe.gating_dropout if cfg.moe is not None else None
@@ -89,6 +104,14 @@ def make_chunk_step(cfg: ModelConfig, tc: TrainConfig,
             return step_fn(s, b, dec)
 
         return jax.lax.scan(body, state, batches)
+
+    state_sharding = train_state_sharding(cfg, tc, ctx)
+    if state_sharding is not None:
+        unpinned = chunk_fn
+
+        def chunk_fn(state, batches, decision):
+            state, ms = unpinned(state, batches, decision)
+            return jax.lax.with_sharding_constraint(state, state_sharding), ms
 
     if jit:
         return jax.jit(chunk_fn, static_argnums=(2,), donate_argnums=(0,))
@@ -159,9 +182,22 @@ class Trainer:
         self.eval_every, self.eval_fn = eval_every, eval_fn
         self.log_every, self.log = log_every, log
         self.prefetch, self.prefetch_depth = prefetch, prefetch_depth
-        if params is None:
-            params = init_model(jax.random.PRNGKey(tc.seed), cfg)
-        self.state = init_train_state(params, tc)
+        self.state_sharding = train_state_sharding(cfg, tc, ctx)
+        self.batch_sharding = None
+        if self.state_sharding is not None:
+            # on a mesh the state is built in place, never gathered on one
+            # device; batches split over the data axes
+            init = lambda p: init_train_state(  # noqa: E731
+                init_model(jax.random.PRNGKey(tc.seed), cfg)
+                if p is None else p, tc)
+            self.state = jax.jit(init, out_shardings=self.state_sharding)(
+                params)
+            self.batch_sharding = jax.sharding.NamedSharding(
+                ctx.mesh, jax.sharding.PartitionSpec(None, ctx.dp_axes))
+        else:
+            if params is None:
+                params = init_model(jax.random.PRNGKey(tc.seed), cfg)
+            self.state = init_train_state(params, tc)
         self.start_step = 0
         self.history: List[Dict] = []
         self.chunk_fn = make_chunk_step(cfg, tc, ctx)
@@ -174,7 +210,8 @@ class Trainer:
         """Restore params + opt + step from ``ckpt_dir`` and continue at
         the ABSOLUTE step: both the data stream (batch_fn) and the
         consensus PRNG (seed, step) pick up exactly where the
-        checkpointed run left off (DESIGN.md §2)."""
+        checkpointed run left off (DESIGN.md §2). On a mesh each leaf
+        lands where the current state's does."""
         assert self.ckpt_dir, "restore() needs ckpt_dir"
         assert latest_step(self.ckpt_dir) is not None, \
             f"restore: no checkpoint in {self.ckpt_dir}"
@@ -220,8 +257,14 @@ class Trainer:
         # read only when tracing (it never syncs, but stays off the
         # steady path regardless)
         n0 = tr.enabled and self.chunk_fn._cache_size()
+
+        def put(v):   # (K, B, ...) batch: B split over the data axes
+            if self.batch_sharding is None:
+                return jnp.asarray(v)
+            return jax.device_put(v, self.batch_sharding)
+
         if self.strategy == "traced_cond":
-            dev = {k: jnp.asarray(v) for k, v in stacked.items()}
+            dev = {k: put(v) for k, v in stacked.items()}
             with tr.span("chunk.execute", start=s, stop=e,
                          decision="traced"), \
                     tr.annotation("train_chunk"):
@@ -230,8 +273,7 @@ class Trainer:
         else:
             parts = []
             for rs, re, dec in same_decision_runs(self.gd, self.tc.seed, s, e):
-                sub = {k: jnp.asarray(v[rs - s:re - s])
-                       for k, v in stacked.items()}
+                sub = {k: put(v[rs - s:re - s]) for k, v in stacked.items()}
                 with tr.span("chunk.execute", start=rs, stop=re,
                              decision=bool(dec)), \
                         tr.annotation("train_chunk"):

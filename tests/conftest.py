@@ -13,8 +13,10 @@ SRC = os.path.join(REPO, "src")
 def run_py(code: str, n_devices: int = 8, timeout: int = 560) -> str:
     """Run python code in a subprocess with N simulated CPU devices.
     Multi-device tests must run out-of-process because jax locks the device
-    count at first init."""
+    count at first init. The child is pinned to the CPU backend: on a host
+    with a chip, the parent may already hold it."""
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,

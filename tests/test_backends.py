@@ -173,16 +173,14 @@ print('OK')
     assert "OK" in out
 
 
-def test_sharded_backend_multidevice_matches_oracle():
-    """Registry-selected sharded backend on a real 8-device mesh equals the
-    oracle with the matching virtual shard count."""
-    out = run_py("""
+def _sharded_vs_oracle(jitter: float) -> str:
+    return run_py(f"""
 import jax, jax.numpy as jnp
 from repro.configs.base import ModelConfig, MoEConfig, GatingDropoutConfig
 from repro.core import get_backend, init_moe_params, moe_oracle, ParallelContext
 from repro.launch.mesh import make_mesh
 cfg = ModelConfig(d_model=32, d_ff=64, vocab=64, moe=MoEConfig(
-    n_experts=8, top_k=2, d_ff_expert=64, jitter_eps=0.0,
+    n_experts=8, top_k=2, d_ff_expert=64, jitter_eps={jitter},
     gating_dropout=GatingDropoutConfig(mode='gate_drop', rate=0.3)))
 p = init_moe_params(jax.random.PRNGKey(0), cfg)
 x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, 32))
@@ -195,4 +193,15 @@ for dec in (False, True):
     assert d < 2e-5, (dec, d)
 print('OK')
 """)
-    assert "OK" in out
+
+
+def test_sharded_backend_multidevice_matches_oracle():
+    """Registry-selected sharded backend on a real 8-device mesh equals the
+    oracle with the matching virtual shard count."""
+    assert "OK" in _sharded_vs_oracle(0.0)
+
+
+def test_sharded_backend_without_rng_adds_no_jitter():
+    """With no rng the router adds no jitter in the sharded backend either,
+    as in the oracle, whatever ``jitter_eps`` says."""
+    assert "OK" in _sharded_vs_oracle(0.01)

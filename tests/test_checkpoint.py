@@ -130,3 +130,57 @@ def test_trainer_resume_continues_identically(tmp_path, strategy):
     assert any(drop_decision_host(gd, 3, i) for i in range(6))
     for a, b in zip(jax.tree.leaves(s_straight), jax.tree.leaves(s_resumed)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_trainer_restore_keeps_experts_split_on_mesh(tmp_path):
+    """On a data=4 mesh the Trainer holds each expert weight and its Adam
+    moments as one quarter of the experts per device, and a restored run
+    puts every leaf back on that layout (never the whole state on one
+    device), with the checkpointed values."""
+    from conftest import run_py
+    out = run_py(f"""
+import jax, numpy as np
+from repro.configs.base import (GatingDropoutConfig, ModelConfig, MoEConfig,
+                                TrainConfig)
+from repro.core.moe import ParallelContext
+from repro.data import LMTaskConfig, SyntheticLM
+from repro.launch.mesh import make_mesh
+from repro.training import Trainer
+ctx = ParallelContext(mesh=make_mesh((4,), ('data',)))
+cfg = ModelConfig(d_model=32, d_ff=64, vocab=64, n_layers=2, n_heads=2,
+                  n_kv_heads=2, remat=False, dtype='float32',
+                  param_dtype='float32',
+                  moe=MoEConfig(n_experts=8, top_k=1, d_ff_expert=64,
+                                backend='sharded', jitter_eps=0.0,
+                                gating_dropout=GatingDropoutConfig(
+                                    mode='gate_drop', rate=0.5,
+                                    strategy='host_cond')))
+task = SyntheticLM(LMTaskConfig(vocab=cfg.vocab, seq_len=16))
+make = lambda steps: Trainer(
+    cfg, TrainConfig(lr=1e-3, warmup_steps=2, seed=0, steps=steps),
+    lambda i: task.sample_batch(i, 8), ctx=ctx, chunk=2,
+    ckpt_dir={str(tmp_path)!r}, log=None)
+
+def experts(state):
+    return {{jax.tree_util.keystr(p): a for p, a in
+            jax.tree_util.tree_leaves_with_path(state) if 'experts' in
+            jax.tree_util.keystr(p)}}
+
+saved, _ = make(2).run()
+tr = make(4)
+assert tr.restore() == 2
+got = experts(tr.state)
+assert len(got) == 9, sorted(got)      # w_in/w_out/w_gate x params, m, v
+for name, a in experts(saved).items():
+    b = got[name]
+    assert b.sharding.is_equivalent_to(a.sharding, a.ndim), (
+        name, b.sharding, a.sharding)
+    shards = {{s.device.id: s.data.shape for s in b.addressable_shards}}
+    assert len(shards) == 4, (name, shards)
+    assert all(s[-3] * 4 == b.shape[-3] for s in shards.values()), (
+        name, b.shape, shards)
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+tr.run()
+print('restored', len(got))
+""", n_devices=4)
+    assert out.split()[-1] == "9"

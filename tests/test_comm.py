@@ -424,7 +424,7 @@ from repro.configs.base import (CommConfig, GatingDropoutConfig, ModelConfig,
 from repro.comm import layer_cost
 from repro.core import init_moe_params, moe_sharded, ParallelContext
 from repro.core import router as R
-from repro.core.moe import _expert_ffn, _shard_map, moe_oracle
+from repro.core.moe import _expert_ffn, moe_oracle
 from repro.analysis import parse_collectives
 from repro.launch.mesh import make_mesh
 
@@ -493,8 +493,9 @@ def legacy(wr, experts, x_loc):
     return R.combine(out, info).reshape(B, L, d)
 espec = {'w_in': P('data', None, None), 'w_out': P('data', None, None),
          'w_gate': P('data', None, None)}
-fn = _shard_map(legacy, ctx.mesh, (P(), espec, P('data', None, None)),
-                P('data', None, None))
+fn = jax.shard_map(legacy, mesh=ctx.mesh,
+                   in_specs=(P(), espec, P('data', None, None)),
+                   out_specs=P('data', None, None), check_vma=False)
 y_legacy = np.asarray(fn(p['router']['w'], p['experts'], x))
 assert np.array_equal(y_legacy, ys['dense']), 'dense != pre-refactor inline'
 print('OK')
@@ -547,13 +548,17 @@ import json
 import jax, jax.numpy as jnp
 from repro.configs.base import (CommConfig, GatingDropoutConfig, ModelConfig,
                                 MoEConfig, TrainConfig)
-from repro.core.gating_dropout import drop_decision_host
+from repro.core.gating_dropout import drop_decision_host, drop_decisions_host
 from repro.core.moe import ParallelContext
 from repro.data import LMTaskConfig, SyntheticLM, stack_batches
 from repro.launch.mesh import make_mesh
 from repro.models import init_model
 from repro.training import Trainer, init_train_state, make_chunk_step
 ctx = ParallelContext(mesh=make_mesh((8,), ('data',)))
+gd_cfg = GatingDropoutConfig(mode='gate_drop', rate=0.5, strategy='host_cond')
+# the first seed whose 6-step window holds both consensus bits
+seed = next(s for s in range(64)
+            if len(set(drop_decisions_host(gd_cfg, s, 0, 6).tolist())) == 2)
 cfg = ModelConfig(d_model=64, d_ff=128, vocab=100, n_layers=1, n_heads=2,
                   n_kv_heads=2, remat=False, dtype='float32',
                   param_dtype='float32',
@@ -561,10 +566,8 @@ cfg = ModelConfig(d_model=64, d_ff=128, vocab=100, n_layers=1, n_heads=2,
                                 backend='sharded',
                                 comm=CommConfig(
                                     substrate='hierarchical_compressed'),
-                                gating_dropout=GatingDropoutConfig(
-                                    mode='gate_drop', rate=0.5,
-                                    strategy='host_cond')))
-tc = TrainConfig(lr=1e-3, warmup_steps=2, seed=3, steps=6)
+                                gating_dropout=gd_cfg))
+tc = TrainConfig(lr=1e-3, warmup_steps=2, seed=seed, steps=6)
 task = SyntheticLM(LMTaskConfig(vocab=cfg.vocab, seq_len=16))
 batches = {k: jnp.asarray(v) for k, v in
            stack_batches(lambda i: task.sample_batch(i, 8), 0, 2).items()}

@@ -143,3 +143,33 @@ def test_main_exec_applies_preset(monkeypatch):
 
 def test_sh_quote_single_quotes():
     assert E._sh_quote("a'b") == "'a'\\''b'"
+
+
+# ------------------------------------------------------ enable_compile_cache
+
+@pytest.fixture
+def jax_cache_config():
+    """Restore JAX's compile-cache directory after the test."""
+    import jax
+    was = jax.config.jax_compilation_cache_dir
+    yield jax.config
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_env_var_is_left_to_jax(monkeypatch, jax_cache_config):
+    before = jax_cache_config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert E.enable_compile_cache() == "/elsewhere/cache"
+    assert jax_cache_config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_fixed_ignored_checkout_path(
+        monkeypatch, jax_cache_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    path = E.enable_compile_cache()
+    assert path == os.path.join(repo, ".jax_cache")
+    assert jax_cache_config.jax_compilation_cache_dir == path
+    assert E.enable_compile_cache() == path
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

@@ -118,3 +118,24 @@ def test_dryrun_comm_table_cli():
     for name in ("dense", "hierarchical", "compressed",
                  "hierarchical_compressed", "vs dense"):
         assert name in stdout, stdout
+
+
+def test_dryrun_import_merges_caller_xla_flags():
+    """Importing the dry-run adds its simulated-device count to the
+    caller's XLA_FLAGS instead of replacing them, and a caller-set device
+    count wins."""
+    code = ("import os; import repro.launch.dryrun; "
+            "print(os.environ['XLA_FLAGS'])")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=SRC + os.pathsep + os.environ.get("PYTHONPATH", ""))
+    for given, want in (
+            ("--xla_cpu_enable_fast_math=false",
+             "--xla_cpu_enable_fast_math=false "
+             "--xla_force_host_platform_device_count=512"),
+            ("--xla_force_host_platform_device_count=8",
+             "--xla_force_host_platform_device_count=8")):
+        r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                           text=True, env=dict(env, XLA_FLAGS=given),
+                           timeout=120)
+        assert r.returncode == 0, r.stderr
+        assert r.stdout.strip() == want

@@ -115,6 +115,17 @@ def test_disabled_tracer_costs_nothing():
     assert dt < 1.0, f"disabled tracer overhead {dt:.3f}s for 100k spans"
 
 
+def test_profile_window_needs_no_host_tracer(tmp_path):
+    """A device profile (train's --jax-profile) does not depend on the
+    host spans being on: a disabled tracer's window still writes one, and
+    with no logdir the window is the shared no-op."""
+    tr = Tracer(enabled=False)
+    assert tr.profile_window(None) is tr.span("a")
+    with tr.profile_window(str(tmp_path)):
+        jnp.ones(4).block_until_ready()
+    assert list(tmp_path.rglob("*.xplane.pb"))
+
+
 # ---------------------------------------------------------------------------
 # MetricsFrame: bitwise non-interference + host-side math
 # ---------------------------------------------------------------------------

@@ -1,0 +1,146 @@
+"""Every main-path Pallas kernel compiles for a described v5e chip.
+
+The TPU compiler (Mosaic) is installed with jax and compiles for a chip
+that is described, not attached, so these run on CPU hosts too. Interpret
+mode cannot see what they check: Mosaic refuses blocks whose last two dims
+are neither (8, 128)-aligned nor the array's own, and kernels that ask for
+more VMEM than the chip gives. Widths are zcode-m3-base's (T=4096 tokens,
+d=512, f=2048, E=128 top-1 experts, bf16 activations; decode at 8 KV heads
+x 64). The topology is described inside a fixture only: the TPU library
+allows one loader per process, and every test worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import (combine, dispatch, flash_decode,
+                           flash_decode_paged, fused_moe_ffn, grouped_matmul)
+
+T, D, F, E = 4096, 512, 2048, 128
+C = T // E                      # capacity at factor 1.0, top-1
+B, H, KV, HD, S = 8, 8, 8, 64, 2048
+PAGE, N_PAGES = 16, 256
+BF16, F32, I32, BOOL = jnp.bfloat16, jnp.float32, jnp.int32, jnp.bool_
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one: keep the cache off meanwhile."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _gmm_grad(x, w):
+    loss = lambda a, b: grouped_matmul(a, b, interpret=False).astype(  # noqa: E731
+        F32).sum()
+    return jax.grad(loss, argnums=(0, 1))(x, w)
+
+
+# kernel -> (fn, argument shapes and dtypes)
+CASES = {
+    "dispatch": (
+        lambda x, st, sv: dispatch(x, st, sv, interpret=False),
+        [((T, D), BF16), ((E * C,), I32), ((E * C,), BOOL)]),
+    "combine": (
+        lambda buf, ts, w, keep: combine(buf, ts, w, keep, interpret=False),
+        [((E * C, D), BF16), ((T, 1), I32), ((T, 1), F32), ((T, 1), BOOL)]),
+    "grouped_matmul": (
+        lambda x, w: grouped_matmul(x, w, interpret=False),
+        [((E, C, D), BF16), ((E, D, F), BF16)]),
+    "grouped_matmul_grad": (
+        _gmm_grad, [((E, C, D), BF16), ((E, D, F), BF16)]),
+    "flash_decode": (
+        lambda q, k, v, i: flash_decode(q, k, v, i, interpret=False),
+        [((B, H, HD), BF16), ((B, S, KV, HD), BF16), ((B, S, KV, HD), BF16),
+         ((B,), I32)]),
+    "flash_decode_paged": (
+        lambda q, k, v, bt, i: flash_decode_paged(q, k, v, bt, i,
+                                                  interpret=False),
+        [((B, H, HD), BF16), ((N_PAGES + 1, PAGE, KV, HD), BF16),
+         ((N_PAGES + 1, PAGE, KV, HD), BF16), ((B, N_PAGES // B), I32),
+         ((B,), I32)]),
+    "megakernel": (
+        lambda x, wi, wo, tw, keep, st, sv, ts: fused_moe_ffn(
+            x, wi, None, wo, tw, keep, st, sv, ts, act="gelu",
+            interpret=False),
+        [((T, D), BF16), ((E, D, F), BF16), ((E, F, D), BF16), ((T, 1), F32),
+         ((T, 1), BOOL), ((E * C,), I32), ((E * C,), BOOL), ((T, 1), I32)]),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(CASES))
+def test_kernel_compiles_for_v5e(kernel, one_chip, no_compile_cache):
+    fn, specs = CASES[kernel]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in specs]
+    txt = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in txt, f"{kernel}: no Mosaic kernel in HLO"
+
+
+def test_encdec_gate_drop_chunk_has_no_alltoall_on_v5e_mesh(topo,
+                                                            no_compile_cache):
+    """The paper's claim under the TPU partitioner, on a described 2x2
+    mesh: the routed host_cond chunk of an encoder-decoder MoE exchanges
+    tokens with all-to-alls and the Gate-Drop chunk has none. (The
+    partitioner re-shards a merged embedding-gradient scatter with
+    all-to-alls that the CPU partitioner never emits.)"""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.configs.base import TrainConfig
+    from repro.core.moe import ParallelContext
+    from repro.models import init_model
+    from repro.training import (init_train_state, make_chunk_step,
+                                train_state_sharding)
+
+    base = get_config("zcode-m3-base")
+    cfg = dataclasses.replace(
+        base, d_model=128, n_heads=2, n_kv_heads=2, d_ff=256, vocab=1024,
+        n_layers=2,
+        encdec=dataclasses.replace(base.encdec, n_encoder_layers=2),
+        moe=dataclasses.replace(base.moe, n_experts=8))
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    ctx = ParallelContext(mesh=mesh)
+    tc = TrainConfig(steps=2)
+    shape = jax.eval_shape(
+        lambda: init_train_state(init_model(jax.random.PRNGKey(0), cfg), tc))
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        shape, train_state_sharding(cfg, tc, ctx))
+    rows = NamedSharding(mesh, P(None, "data"))
+    batch = {k: jax.ShapeDtypeStruct((1, 8, 32), F32 if k == "loss_mask"
+                                     else I32, sharding=rows)
+             for k in ("enc_tokens", "tokens", "labels", "loss_mask")}
+    chunk = make_chunk_step(cfg, tc, ctx)
+    a2a = {dec: chunk.lower(state, batch, dec).compile().as_text().count(
+        "all-to-all(") for dec in (False, True)}
+    assert a2a[False] > 0 and a2a[True] == 0, a2a
