@@ -504,9 +504,11 @@ def moe_apply(params: Params, x: jax.Array, cfg: ModelConfig,
     mesh is active, oracle otherwise. ``token_valid`` (same leading shape
     as ``x``'s token dims) marks tokens from retired/empty serving slots:
     they are routed but never dispatched, so they cannot steal expert
-    capacity from live tokens (DESIGN.md §9)."""
+    capacity from live tokens (DESIGN.md §9). Its ops carry the
+    ``moe`` name scope, which a profiler trace reads back."""
     from repro.core import backend as B
     fn = B.get_backend(B.resolve_backend(cfg.moe, ctx))
-    return fn(params, x, cfg, ctx, rng=rng, decision=decision,
-              is_training=is_training, token_ids=token_ids,
-              token_valid=token_valid)
+    with jax.named_scope("moe"):
+        return fn(params, x, cfg, ctx, rng=rng, decision=decision,
+                  is_training=is_training, token_ids=token_ids,
+                  token_valid=token_valid)

@@ -242,49 +242,52 @@ def _layer_apply(spec: LayerSpec, p: Params, x: jax.Array, cfg: ModelConfig,
         h = L.norm_apply(p["ln1"], x, cfg)
         outs = []
         if spec.mixer in ("gqa", "hybrid"):
-            if mode == "decode":
-                # windowed layers keep their slot-addressed ring cache;
-                # only full-cache layers read through the page table
-                o, nc = A.decode_self_attention(
-                    p["attn"], h, cache["attn"], cfg, index,
-                    window=spec.window, flash=flash_decode,
-                    block_tables=None if spec.window > 0 else block_tables)
-                new_cache["attn"] = nc
-            else:
-                q, k, v = A.attn_qkv(p["attn"], h)
-                pos = jnp.arange(l)
-                q = L.apply_rope(q, pos, cfg.rope_theta)
-                k = L.apply_rope(k, pos, cfg.rope_theta)
-                if (cfg.banded_swa and spec.window > 0 and spec.causal
-                        and l > 2 * spec.window):
-                    from repro.models.flash import banded_flash_attention
-                    qc = 1024 if l % 1024 == 0 or l > 4096 else 512
-                    o = banded_flash_attention(q, k, v, spec.window,
-                                               q_chunk=qc, kv_chunk=512,
-                                               use_full=not cfg.scan_layers)
+            with jax.named_scope("attention"):
+                if mode == "decode":
+                    # windowed layers keep their slot-addressed ring cache;
+                    # only full-cache layers read through the page table
+                    o, nc = A.decode_self_attention(
+                        p["attn"], h, cache["attn"], cfg, index,
+                        window=spec.window, flash=flash_decode,
+                        block_tables=(None if spec.window > 0
+                                      else block_tables))
+                    new_cache["attn"] = nc
                 else:
-                    o = A.flash_attention(q, k, v, causal=spec.causal,
-                                          window=spec.window)
-                o = A.attn_out(p["attn"], o, x.dtype)
-                if mode == "prefill":
-                    new_cache["attn"] = _fill_kv_cache(
-                        spec, cfg, cache["attn"], k, v)
+                    q, k, v = A.attn_qkv(p["attn"], h)
+                    pos = jnp.arange(l)
+                    q = L.apply_rope(q, pos, cfg.rope_theta)
+                    k = L.apply_rope(k, pos, cfg.rope_theta)
+                    if (cfg.banded_swa and spec.window > 0 and spec.causal
+                            and l > 2 * spec.window):
+                        from repro.models.flash import banded_flash_attention
+                        qc = 1024 if l % 1024 == 0 or l > 4096 else 512
+                        o = banded_flash_attention(
+                            q, k, v, spec.window, q_chunk=qc, kv_chunk=512,
+                            use_full=not cfg.scan_layers)
+                    else:
+                        o = A.flash_attention(q, k, v, causal=spec.causal,
+                                              window=spec.window)
+                    o = A.attn_out(p["attn"], o, x.dtype)
+                    if mode == "prefill":
+                        new_cache["attn"] = _fill_kv_cache(
+                            spec, cfg, cache["attn"], k, v)
             outs.append(o)
         if spec.mixer == "mla":
-            if mode == "decode":
-                o, nc = M.mla_decode(p["attn"], h, cache["attn"], cfg, index,
-                                     block_tables=block_tables)
-                new_cache["attn"] = nc
-            else:
-                o, (c_kv, k_rope) = M.mla_attention(p["attn"], h, cfg,
-                                                    return_cache=True)
-                if mode == "prefill":
-                    smax = cache["attn"]["c_kv"].shape[1]
-                    cdt = cache["attn"]["c_kv"].dtype
-                    new_cache["attn"] = {
-                        "c_kv": _pad_to(c_kv.astype(cdt), smax, 1),
-                        "k_rope": _pad_to(k_rope.astype(cdt), smax, 1),
-                    }
+            with jax.named_scope("attention"):
+                if mode == "decode":
+                    o, nc = M.mla_decode(p["attn"], h, cache["attn"], cfg,
+                                         index, block_tables=block_tables)
+                    new_cache["attn"] = nc
+                else:
+                    o, (c_kv, k_rope) = M.mla_attention(p["attn"], h, cfg,
+                                                        return_cache=True)
+                    if mode == "prefill":
+                        smax = cache["attn"]["c_kv"].shape[1]
+                        cdt = cache["attn"]["c_kv"].dtype
+                        new_cache["attn"] = {
+                            "c_kv": _pad_to(c_kv.astype(cdt), smax, 1),
+                            "k_rope": _pad_to(k_rope.astype(cdt), smax, 1),
+                        }
             outs.append(o)
         if spec.mixer in ("ssm", "hybrid"):
             if mode == "decode":
@@ -305,16 +308,19 @@ def _layer_apply(spec: LayerSpec, p: Params, x: jax.Array, cfg: ModelConfig,
     # ---- cross attention ----
     if spec.cross:
         h = L.norm_apply(p["ln_cross"] if "ln_cross" in p else p["ln1"], x, cfg)
-        if mode == "decode" or cross_src is None:
-            ck, cv = cache["cross"]["k"], cache["cross"]["v"]
-        else:
-            ck, cv = A.make_cross_kv(p["cross"], cross_src)
-            if mode == "prefill":
-                cdt = cache["cross"]["k"].dtype
-                new_cache["cross"] = {"k": ck.astype(cdt), "v": cv.astype(cdt)}
-        o = A.cross_attention_kv(p["cross"], h, ck, cv)
-        if spec.gated_cross:
-            o = jnp.tanh(p["gate_attn"].astype(jnp.float32)).astype(o.dtype) * o
+        with jax.named_scope("attention"):
+            if mode == "decode" or cross_src is None:
+                ck, cv = cache["cross"]["k"], cache["cross"]["v"]
+            else:
+                ck, cv = A.make_cross_kv(p["cross"], cross_src)
+                if mode == "prefill":
+                    cdt = cache["cross"]["k"].dtype
+                    new_cache["cross"] = {"k": ck.astype(cdt),
+                                          "v": cv.astype(cdt)}
+            o = A.cross_attention_kv(p["cross"], h, ck, cv)
+            if spec.gated_cross:
+                gate = jnp.tanh(p["gate_attn"].astype(jnp.float32))
+                o = gate.astype(o.dtype) * o
         x = x + o
         if mode in ("prefill", "decode") and "cross" not in new_cache:
             new_cache["cross"] = cache["cross"]   # carried through unchanged

@@ -1,11 +1,15 @@
 """Host-side span tracer with Chrome-trace/Perfetto export (DESIGN.md §15).
 
-One tracer serves the whole process: the Trainer's chunk
-dispatch/execute/fetch phases, the Prefetcher's produce/wait pair (on its
-worker thread), and the serving schedulers' tick phases all record into
-it. Events live in host memory as plain tuples until ``export`` writes
-the Chrome trace-event JSON (load the file at https://ui.perfetto.dev
-or chrome://tracing).
+One tracer serves the whole process: the Trainer's chunk phases
+(decide/put/execute/fetch/record), the Prefetcher's produce/wait pair (on
+its worker thread), and the serving schedulers' tick phases all record
+into it. Events live in host memory as plain tuples until ``export``
+writes the Chrome trace-event JSON (load the file at
+https://ui.perfetto.dev or chrome://tracing). An enabled tracer's spans
+also enter a ``jax.profiler.TraceAnnotation`` of the same name and args,
+so inside a ``jax.profiler.trace`` window the profiler writes them on
+the trace's own clock, on the recording thread's line, beside the
+device's ops.
 
 Design constraints:
 
@@ -18,10 +22,11 @@ Design constraints:
     returns a shared no-op context manager after a single attribute
     check — no object allocation, no clock read, no event
     (``tests/test_obs.py::test_disabled_tracer_costs_nothing``).
-  * Zero device interaction. Recording touches only the clock and a
-    list append, so instrumented code stays green under the
-    ``analysis.hostsync`` guard; span ``args`` must already be host
-    scalars (never jax arrays — stringifying one would sync).
+  * Zero device interaction. Recording touches only the clock, a list
+    append and the profiler's host annotation, so instrumented code
+    stays green under the ``analysis.hostsync`` guard; span ``args``
+    must already be host scalars (never jax arrays — stringifying one
+    would sync).
   * Thread safety by construction: ``list.append`` is atomic under the
     GIL and each event carries its recording thread's id; export maps
     the ids to dense Perfetto track numbers with ``thread_name``
@@ -34,6 +39,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Tracer", "get_tracer", "monotonic", "set_tracer"]
 
@@ -62,18 +69,22 @@ _NULL = _NullCtx()
 
 
 class _Span:
-    """One open span; records a complete ('X') event on exit."""
-    __slots__ = ("_tr", "_name", "_args", "_t0")
+    """One open span; records a complete ('X') event on exit. Its
+    profiler annotation is what a ``jax.profiler`` trace records."""
+    __slots__ = ("_tr", "_name", "_args", "_t0", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, args: Dict[str, Any]):
         self._tr, self._name, self._args = tr, name, args
+        self._ann = TraceAnnotation(name, **args)
 
     def __enter__(self) -> "_Span":
+        self._ann.__enter__()
         self._t0 = monotonic()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = monotonic()
+        self._ann.__exit__(*exc)
         self._tr._record("X", self._name, self._t0, t1 - self._t0,
                          self._args)
         return False
@@ -90,8 +101,8 @@ class Tracer:
 
     ``span(name, **args)`` is a context manager (nesting = call-stack
     containment, rendered as stacked slices per thread); ``instant``
-    marks a point ('i' event, e.g. a jit retrace or a prefix-cache hit);
-    ``counter`` records a 'C' series. ``export(path)`` writes
+    marks a point ('i' event, e.g. a jit retrace or a prefix-cache
+    hit). ``export(path)`` writes
     ``{"traceEvents": [...]}`` with timestamps in µs since the tracer's
     epoch."""
 
@@ -124,21 +135,7 @@ class Tracer:
             return
         self._record("i", name, monotonic(), 0.0, args)
 
-    def counter(self, name: str, **values) -> None:
-        if not self.enabled:
-            return
-        self._record("C", name, monotonic(), 0.0, values)
-
-    # -- device-timeline hooks ---------------------------------------------
-
-    def annotation(self, name: str):
-        """Name the enclosed compiled dispatch on the device timeline
-        (``jax.profiler.TraceAnnotation``) — only meaningful inside a
-        ``jax.profiler`` window, free no-op otherwise."""
-        if not self.enabled:
-            return _NULL
-        import jax.profiler
-        return jax.profiler.TraceAnnotation(name)
+    # -- device profile ----------------------------------------------------
 
     def profile_window(self, logdir: Optional[str]):
         """``jax.profiler.trace`` window writing a TensorBoard-loadable
@@ -194,7 +191,7 @@ class Tracer:
                 "args": {k: _jsonable(v) for k, v in args.items()}}
             if ph == "X":
                 ev["dur"] = dur * 1e6
-            elif ph == "i":
+            else:
                 ev["s"] = "t"
             out.append(ev)
         doc = {"traceEvents": out, "displayTimeUnit": "ms"}

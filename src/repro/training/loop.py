@@ -250,7 +250,10 @@ class Trainer:
         """Run one chunk; returns per-step metrics stacked over the span
         (the chunk's ONLY host-device sync, via an explicit
         jax.device_get — the analysis.hostsync guard flags implicit
-        pulls inside steady-state ticks)."""
+        pulls inside steady-state ticks). Each host phase has a span:
+        ``chunk.decide`` (host_cond's consensus draw), then per
+        same-decision run ``chunk.put`` and ``chunk.execute`` (the
+        enqueue), then ``chunk.fetch``."""
         s, e = span
         tr = self.tracer
         # jit-retrace detection: _cache_size is host-only introspection,
@@ -264,27 +267,69 @@ class Trainer:
             return jax.device_put(v, self.batch_sharding)
 
         if self.strategy == "traced_cond":
-            dev = {k: put(v) for k, v in stacked.items()}
-            with tr.span("chunk.execute", start=s, stop=e,
-                         decision="traced"), \
-                    tr.annotation("train_chunk"):
-                self.state, ms = self.chunk_fn(self.state, dev, None)
-            parts = [ms]
+            runs = [(s, e, None)]
         else:
-            parts = []
-            for rs, re, dec in same_decision_runs(self.gd, self.tc.seed, s, e):
+            with tr.span("chunk.decide", start=s, stop=e):
+                runs = same_decision_runs(self.gd, self.tc.seed, s, e)
+        parts = []
+        for rs, re, dec in runs:
+            with tr.span("chunk.put", start=rs, stop=re):
                 sub = {k: put(v[rs - s:re - s]) for k, v in stacked.items()}
-                with tr.span("chunk.execute", start=rs, stop=re,
-                             decision=bool(dec)), \
-                        tr.annotation("train_chunk"):
-                    self.state, m = self.chunk_fn(self.state, sub, dec)
-                parts.append(m)
+            with tr.span("chunk.execute", start=rs, stop=re,
+                         decision="traced" if dec is None else bool(dec)):
+                self.state, m = self.chunk_fn(self.state, sub, dec)
+            parts.append(m)
         if tr.enabled and self.chunk_fn._cache_size() > n0:
             tr.instant("jit_retrace", fn="chunk_fn", start=s, stop=e)
         with tr.span("chunk.fetch", start=s, stop=e):
             parts = jax.device_get(parts)
-        return {k: np.concatenate([p[k] for p in parts])
-                for k in parts[0]}
+            return {k: np.concatenate([p[k] for p in parts])
+                    for k in parts[0]}
+
+    def _record(self, s: int, e: int, ms: Dict[str, np.ndarray], el: float,
+                tok_s: float, rec_steps: set, eval_steps: set) -> None:
+        """History records of the chunk's recorded steps, from its fetched
+        metrics; ``el`` is the chunk-boundary time since the run began."""
+        for i in range(s, e):
+            if i not in rec_steps:
+                continue
+            j = i - s
+            # tok_s pairs the CHUNK-complete token count with the
+            # chunk-boundary timestamp (el) — same convention as
+            # time_s; pro-rating tokens to step i against el would
+            # understate mid-chunk throughput
+            rec = {"step": i, "loss": float(ms["loss"][j]),
+                   "acc": float(ms["acc"][j]),
+                   "lr": float(ms["lr"][j]),
+                   "tok_s": tok_s,
+                   "time_s": el}
+            if "balance" in ms:
+                rec["balance"] = float(ms["balance"][j])
+            if "comm_wire_bytes" in ms:
+                # per-device wire bytes this step's forward moved
+                # (in-graph substrate telemetry, DESIGN.md §10)
+                rec["comm_wire_bytes"] = float(ms["comm_wire_bytes"][j])
+                rec["comm_a2a_calls"] = float(ms["comm_a2a_calls"][j])
+                # exposed vs hidden wire (DESIGN.md §14): what an
+                # overlapped substrate could NOT pipeline behind
+                # expert compute this step
+                rec["comm_exposed_bytes"] = float(
+                    ms["comm_exposed_bytes"][j])
+                rec["comm_hidden_bytes"] = float(ms["comm_hidden_bytes"][j])
+            if "router_entropy" in ms:
+                # MetricsFrame router-health fields (§15): per-
+                # step entropy / load imbalance / consensus bit,
+                # already on host from the chunk fetch
+                rec["router_entropy"] = float(ms["router_entropy"][j])
+                rec["load_imbalance"] = float(load_imbalance(
+                    np.asarray(ms["expert_load"][j])))
+                rec["gate_dropped"] = float(ms["gate_dropped"][j])
+            if i in eval_steps:   # schedule guarantees i == e - 1
+                with self.tracer.span("eval", step=i):
+                    rec.update(self.eval_fn(self.state, i))
+            self.history.append(rec)
+            if self.log is not None:
+                self.log(json.dumps(rec))
 
     def run(self) -> Tuple[Any, List[Dict]]:
         tc = self.tc
@@ -300,56 +345,16 @@ class Trainer:
                 s, e = span
                 tok_per_step = sum(int(stacked[k][0].size)
                                    for k in TOKEN_KEYS if k in stacked)
+                # ``positions`` counts padded positions, not tokens
                 with self.tracer.span("train_chunk", start=s, stop=e,
                                       strategy=self.strategy,
-                                      tokens=(e - s) * tok_per_step):
+                                      positions=(e - s) * tok_per_step):
                     ms = self._dispatch(span, stacked)
-                el = monotonic() - t0
-                tokens_done += (e - s) * tok_per_step
-                for i in range(s, e):
-                    if i not in rec_steps:
-                        continue
-                    j = i - s
-                    # tok_s pairs the CHUNK-complete token count with the
-                    # chunk-boundary timestamp (el) — same convention as
-                    # time_s; pro-rating tokens to step i against el would
-                    # understate mid-chunk throughput
-                    rec = {"step": i, "loss": float(ms["loss"][j]),
-                           "acc": float(ms["acc"][j]),
-                           "lr": float(ms["lr"][j]),
-                           "tok_s": tokens_done / max(el, 1e-9),
-                           "time_s": el}
-                    if "balance" in ms:
-                        rec["balance"] = float(ms["balance"][j])
-                    if "comm_wire_bytes" in ms:
-                        # per-device wire bytes this step's forward moved
-                        # (in-graph substrate telemetry, DESIGN.md §10)
-                        rec["comm_wire_bytes"] = float(
-                            ms["comm_wire_bytes"][j])
-                        rec["comm_a2a_calls"] = float(
-                            ms["comm_a2a_calls"][j])
-                        # exposed vs hidden wire (DESIGN.md §14): what an
-                        # overlapped substrate could NOT pipeline behind
-                        # expert compute this step
-                        rec["comm_exposed_bytes"] = float(
-                            ms["comm_exposed_bytes"][j])
-                        rec["comm_hidden_bytes"] = float(
-                            ms["comm_hidden_bytes"][j])
-                    if "router_entropy" in ms:
-                        # MetricsFrame router-health fields (§15): per-
-                        # step entropy / load imbalance / consensus bit,
-                        # already on host from the chunk fetch
-                        rec["router_entropy"] = float(
-                            ms["router_entropy"][j])
-                        rec["load_imbalance"] = float(load_imbalance(
-                            np.asarray(ms["expert_load"][j])))
-                        rec["gate_dropped"] = float(ms["gate_dropped"][j])
-                    if i in eval_steps:   # schedule guarantees i == e - 1
-                        with self.tracer.span("eval", step=i):
-                            rec.update(self.eval_fn(self.state, i))
-                    self.history.append(rec)
-                    if self.log is not None:
-                        self.log(json.dumps(rec))
+                    with self.tracer.span("chunk.record", start=s, stop=e):
+                        el = monotonic() - t0
+                        tokens_done += (e - s) * tok_per_step
+                        self._record(s, e, ms, el, tokens_done / max(el, 1e-9),
+                                     rec_steps, eval_steps)
         finally:
             if isinstance(it, Prefetcher):
                 it.close()

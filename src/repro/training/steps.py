@@ -107,10 +107,11 @@ def total_loss(params, batch, cfg: ModelConfig, ctx, *, rng, decision,
                               return_hidden=True)
     labels = batch["labels"]
     mask = batch.get("loss_mask")
-    head = head_matrix(params, cfg)
-    loss, acc = chunked_xent(hidden, head, labels, mask,
-                             chunk=512 if cfg.scan_layers
-                             else hidden.shape[1])
+    with jax.named_scope("lm_head"):
+        head = head_matrix(params, cfg)
+        loss, acc = chunked_xent(hidden, head, labels, mask,
+                                 chunk=512 if cfg.scan_layers
+                                 else hidden.shape[1])
     metrics = {"xent": loss, "acc": acc}
     nmoe = n_moe_layers(cfg)
     if cfg.moe is not None:
@@ -145,7 +146,8 @@ def total_loss(params, batch, cfg: ModelConfig, ctx, *, rng, decision,
         m2 = (mask if mask is not None else jnp.ones_like(labels, jnp.float32))
         m2 = m2 * jnp.roll(m2, -1, axis=1)
         m2 = m2.at[:, -1].set(0.0)
-        mtp_l, _ = chunked_xent(aux["mtp_hidden"], head, labels2, m2)
+        with jax.named_scope("lm_head"):
+            mtp_l, _ = chunked_xent(aux["mtp_hidden"], head, labels2, m2)
         loss = loss + 0.3 * mtp_l
         metrics["mtp_xent"] = mtp_l
     metrics["loss"] = loss
@@ -210,8 +212,9 @@ def make_train_step(cfg: ModelConfig, tc: TrainConfig,
                 g_sum, m_sum, _ = carry
             grads = jax.tree.map(lambda g: g / k, g_sum)
             metrics = jax.tree.map(lambda m: m / k, m_sum)
-        new_params, new_opt, opt_m = adam_update(grads, state["opt"],
-                                                 state["params"], tc)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_m = adam_update(grads, state["opt"],
+                                                     state["params"], tc)
         metrics.update(opt_m)
         if frame and cfg.moe is not None:
             # the frame's gate-drop decision-rate field: the step's

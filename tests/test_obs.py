@@ -12,6 +12,7 @@ device->host syncs.
 """
 import dataclasses
 import json
+import re
 import threading
 
 import jax
@@ -19,14 +20,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import get_config
 from repro.configs.base import (GatingDropoutConfig, ModelConfig, MoEConfig,
-                                TrainConfig)
+                                TrainConfig, reduced)
 from repro.data import LMTaskConfig, SyntheticLM
+from repro.data.pipeline import MTTaskConfig, MultilingualMT
+from repro.data.prefetch import stack_batches
 from repro.models import init_model
 from repro.obs import (FRAME_KEYS, MetricsFrame, MetricsRegistry, Tracer,
                        load_imbalance, monotonic, router_health)
 from repro.serve import ContinuousScheduler, GenerateConfig, Request
 from repro.training import Trainer, init_train_state, make_train_step
+from repro.training.loop import make_chunk_step
 
 KEY = jax.random.PRNGKey(0)
 
@@ -59,7 +64,6 @@ def test_span_nesting_and_export_schema(tmp_path):
                          name="worker")
     t.start()
     t.join()
-    tr.counter("alive", slots=2)
 
     path = tmp_path / "trace.json"
     tr.export(str(path))
@@ -80,7 +84,6 @@ def test_span_nesting_and_export_schema(tmp_path):
     assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-6
     assert by_name["mark"]["s"] == "t"
     assert by_name["mark"]["args"] == {"hit": True}
-    assert by_name["alive"]["ph"] == "C"
     # the worker-thread instant landed on its own dense track
     assert by_name["from_worker"]["tid"] != by_name["outer"]["tid"]
 
@@ -271,28 +274,94 @@ def test_registry_kind_collision_asserts():
 # instrumentation coverage: trainer + scheduler under the hostsync guard
 # ---------------------------------------------------------------------------
 
-def test_trainer_instrumentation_coverage():
+@pytest.mark.parametrize("strategy", ["traced_cond", "host_cond"])
+def test_trainer_instrumentation_coverage(strategy):
     """A tiny instrumented Trainer run emits the §15 span vocabulary
-    (chunk dispatch/execute/fetch + prefetch produce/wait) and the
-    MetricsFrame lands in the history records — with this module under
-    the conftest transfer guard, the run also proves the tracer adds no
-    hidden host syncs."""
+    (chunk put/execute/fetch/record, host_cond's decide, prefetch
+    produce/wait) and the MetricsFrame lands in the history records —
+    with this module under the conftest transfer guard, the run also
+    proves the tracer adds no hidden host syncs."""
     cfg = _cfg()
     tc = TrainConfig(lr=1e-3, warmup_steps=2, steps=4, seed=0)
     task = SyntheticLM(LMTaskConfig(vocab=cfg.vocab, seq_len=16))
     tracer = Tracer(enabled=True)
     trainer = Trainer(cfg, tc, lambda i: task.sample_batch(i, 4), chunk=2,
-                      strategy="traced_cond", log=None, tracer=tracer)
+                      strategy=strategy, log=None, tracer=tracer)
     _, history = trainer.run()
     names = {e[1] for e in tracer.events}
-    assert {"train_chunk", "chunk.execute", "chunk.fetch",
-            "prefetch.produce", "prefetch.wait"} <= names
+    assert {"train_chunk", "chunk.put", "chunk.execute", "chunk.fetch",
+            "chunk.record", "prefetch.produce", "prefetch.wait"} <= names
+    assert ("chunk.decide" in names) == (strategy == "host_cond")
     assert history
     for rec in history:
         assert {"router_entropy", "load_imbalance",
                 "gate_dropped"} <= set(rec)
     # the exported trace of a real run is loadable Chrome JSON
     json.dumps(tracer.export())
+
+
+# the name scopes a profiler trace reads the train step's layers by
+LAYER_SCOPES = ("moe", "attention", "lm_head", "optimizer")
+
+
+def _scopes(op_name: str) -> set:
+    """Scopes named in an op's ``op_name`` metadata: path components
+    equal to a scope, bare or wrapped by a transform, as in
+    ``transpose(jvp(lm_head))``. Fusions join their ops' names by ';'."""
+    comps = [c for seg in op_name.split(";") for c in seg.split("/")]
+    return {s for s in LAYER_SCOPES for c in comps
+            if re.fullmatch(r"(?:[\w]+\()*" + s + r"\)*", c)}
+
+
+@pytest.mark.parametrize("decision", [False, True])
+def test_host_cond_executables_carry_layer_scopes(decision):
+    """Both host_cond executables of a tiny encoder-decoder MoE step
+    (scanned, rematted layers) carry every layer scope in their compiled
+    HLO, forward and backward."""
+    cfg = reduced(get_config("zcode-m3-base"), remat=True)
+    tc = TrainConfig(steps=1)
+    task = MultilingualMT(MTTaskConfig(vocab=cfg.vocab, max_len=16,
+                                       src_len=(4, 12)))
+    batches = stack_batches(lambda i: task.sample_batch(i, 2), 0, 1)
+    state = init_train_state(init_model(KEY, cfg), tc)
+    text = make_chunk_step(cfg, tc).lower(state, batches,
+                                          decision).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    found = set().union(*map(_scopes, names))
+    assert found == set(LAYER_SCOPES)
+    backward = set().union(*(_scopes(n) for n in names if "transpose(" in n))
+    assert {"moe", "attention", "lm_head"} <= backward
+    assert not _scopes("state['params']['moe']['router']['w']")
+
+
+def test_spans_are_profiler_host_events(tmp_path):
+    """A host_cond Trainer run with an enabled tracer inside a
+    ``jax.profiler`` window: the profiler writes every chunk phase's span
+    on the driving thread's line of the host plane, and the prefetch
+    worker's spans on a line of their own."""
+    from jax.profiler import ProfileData
+    cfg = _cfg()
+    tc = TrainConfig(lr=1e-3, warmup_steps=2, steps=4, seed=0)
+    task = SyntheticLM(LMTaskConfig(vocab=cfg.vocab, seq_len=16))
+    trainer = Trainer(cfg, tc, lambda i: task.sample_batch(i, 4), chunk=2,
+                      strategy="host_cond", log=None,
+                      tracer=Tracer(enabled=True))
+    trainer.run()                       # compile outside the window
+    trainer.start_step, trainer.tc = 4, dataclasses.replace(tc, steps=8)
+    with jax.profiler.trace(str(tmp_path)):
+        trainer.run()
+    path, = tmp_path.rglob("*.xplane.pb")
+    host, = [p for p in ProfileData.from_file(str(path)).planes
+             if p.name == "/host:CPU"]
+    lines = [{e.name for e in line.events} for line in host.lines]
+    driving = [i for i, names in enumerate(lines) if "train_chunk" in names]
+    assert len(driving) == 1
+    assert {"train_chunk", "chunk.decide", "chunk.put", "chunk.execute",
+            "chunk.fetch", "chunk.record",
+            "prefetch.wait"} <= lines[driving[0]]
+    produce = [i for i, names in enumerate(lines)
+               if "prefetch.produce" in names]
+    assert produce and driving[0] not in produce
 
 
 def test_scheduler_obs_and_compat_views():
