@@ -9,7 +9,12 @@ Numerically-identical implementations, selected via the execution-backend
 registry (core/backend.py, DESIGN.md §6; ``MoEConfig.backend``):
 
   * ``moe_oracle``   -- pure jnp, `ep` *virtual* shards (vmap). Used on CPU,
-                        in tests, and as the ground truth for the sharded path.
+                        on one chip, in tests, and as the ground truth for
+                        the sharded path. Only routing, dispatch and combine
+                        are vmapped; both Gate-Drop branches run the expert
+                        FFN once over all experts, outside the vmap, since a
+                        vmapped FFN transposes the weight gradient's layout
+                        (see ``moe_oracle``).
   * ``moe_sharded``  -- shard_map over the real mesh; the dispatch/combine
                         all-to-alls are explicit ``jax.lax.all_to_all`` over
                         the `data` axis.
@@ -250,14 +255,14 @@ def _routed_shard(wr, experts, xf, moe: MoEConfig, cfg: ModelConfig, rng,
     return y, _routed_aux(rr, info, moe, comm=comm_t)
 
 
-def _local_shard(wr, experts_loc, xf, moe: MoEConfig, cfg: ModelConfig, rng,
-                 is_training, token_ids, my_shard, ep: int, tp_axis,
-                 token_valid=None):
-    """Gate-Drop local step: tokens stay on this shard, routed among the
-    local expert group only. No collective over the data axis."""
+def _local_route(wr, xf, moe: MoEConfig, rng, is_training, token_ids,
+                 my_shard, ep: int, token_valid=None):
+    """Gate-Drop routing of one shard's tokens among its local expert group:
+    route, the local-combine override, the validity mask and the dispatch
+    info over shard-local expert ids. Returns ``(rr, info, cap)``; ``rr``
+    keeps GLOBAL expert ids (for ``_local_aux``)."""
     T = xf.shape[0]
-    E = moe.n_experts
-    e_loc = E // ep
+    e_loc = moe.n_experts // ep
     lo = my_shard * e_loc
     rr = R.route(wr, xf, moe, rng=_shard_rng(rng, my_shard),
                  is_training=is_training, token_ids=token_ids,
@@ -269,10 +274,20 @@ def _local_shard(wr, experts_loc, xf, moe: MoEConfig, cfg: ModelConfig, rng,
     cf = moe.capacity_factor if is_training else moe.eval_capacity_factor
     cap = min(R.capacity(T, e_loc, moe.top_k, cf), T)
     info = R.dispatch_info(rr_local, e_loc, cap, valid=valid)
-    buf = R.dispatch(xf, info, e_loc, cap)                   # (e_loc, cap, d)
+    return rr, info, cap
+
+
+def _local_shard(wr, experts_loc, xf, moe: MoEConfig, cfg: ModelConfig, rng,
+                 is_training, token_ids, my_shard, ep: int, tp_axis,
+                 token_valid=None):
+    """Gate-Drop local step: tokens stay on this shard, routed among the
+    local expert group only. No collective over the data axis."""
+    rr, info, cap = _local_route(wr, xf, moe, rng, is_training, token_ids,
+                                 my_shard, ep, token_valid)
+    buf = R.dispatch(xf, info, moe.n_experts // ep, cap)     # (e_loc, cap, d)
     out = _expert_ffn(experts_loc, buf, cfg, tp_axis)
     y = R.combine(out, info)
-    return y, _local_aux(rr, info, moe, T)
+    return y, _local_aux(rr, info, moe, xf.shape[0])
 
 
 def _zero_aux(E: int):
@@ -292,7 +307,16 @@ def moe_oracle(params: Params, x: jax.Array, cfg: ModelConfig, *,
                token_ids: Optional[jax.Array] = None,
                token_valid: Optional[jax.Array] = None
                ) -> Tuple[jax.Array, Dict]:
-    """Reference MoE with `ep` virtual machines. x: (B, L, d) or (T, d)."""
+    """Reference MoE with `ep` virtual machines. x: (B, L, d) or (T, d).
+
+    Routing, dispatch and combine run per virtual shard under ``vmap``;
+    the expert FFN runs once, outside it, on the shards' buffers laid out
+    in expert order, in the routed branch and the Gate-Drop local branch
+    alike. A vmapped expert FFN gives the expert weights' gradient a
+    transposed layout, which Adam's update follows: on a TPU v5e that cost
+    copies of every expert leaf of the train state (parameters and both
+    moments) into and back out of that layout, 24 whole-leaf copies a
+    dropped step at zcode-m3-base."""
     moe = cfg.moe
     shape = x.shape
     xf = x.reshape(-1, shape[-1])
@@ -343,16 +367,23 @@ def moe_oracle(params: Params, x: jax.Array, cfg: ModelConfig, *,
     def local():
         e_loc = E // ep
 
-        def shard_local(my, xl, tl, tvl):
-            ex_loc = jax.tree.map(lambda w: jax.lax.dynamic_slice_in_dim(
-                w, my * e_loc, e_loc, axis=0), experts)
-            return _local_shard(wr, ex_loc, xl, moe, cfg, rng, is_training,
-                                tl, my, ep, None, token_valid=tvl)
+        def shard_dispatch(my, xl, tl, tvl):
+            rr, info, cap = _local_route(wr, xl, moe, rng, is_training, tl,
+                                         my, ep, token_valid=tvl)
+            return R.dispatch(xl, info, e_loc, cap), info, rr
 
-        ys, auxs = jax.vmap(
-            shard_local, in_axes=(0, 0, 0 if tok is not None else None,
-                                  0 if tv is not None else None))(
+        bufs, infos, rrs = jax.vmap(
+            shard_dispatch, in_axes=(0, 0, 0 if tok is not None else None,
+                                     0 if tv is not None else None))(
             jnp.arange(ep), xs, tok, tv)
+        # (ep, e_loc, cap, d) -> (E, cap, d): shard my's experts are rows
+        # my*e_loc.., so this is expert order. One FFN over all experts,
+        # outside the vmap, as in routed().
+        outs = _expert_ffn(experts, bufs.reshape(E, *bufs.shape[2:]), cfg,
+                           None).reshape(bufs.shape)
+        ys = jax.vmap(R.combine)(outs, infos)
+        auxs = jax.vmap(lambda r, i: _local_aux(r, i, moe, T // ep))(
+            rrs, infos)
         return ys.reshape(T, -1), jax.tree.map(lambda a: a.mean(0), auxs)
 
     def expert_drop():

@@ -201,3 +201,95 @@ for dec in (None, True, False):
 print('OK')
 """)
     assert "OK" in out
+
+
+def _vmapped_ffn_local(params, x, cfg, ep, rng, token_ids, token_valid):
+    """The oracle's Gate-Drop local branch as it was before the expert FFN
+    left the virtual-shard vmap, kept verbatim (the shard body inlined):
+    each virtual shard slices its experts and runs its own FFN."""
+    from repro.core import router as R
+    from repro.core.moe import (_expert_ffn, _local_adjust, _local_aux,
+                                _shard_rng)
+    moe = cfg.moe
+    shape = x.shape
+    xf = x.reshape(-1, shape[-1])
+    T = xf.shape[0]
+    xs = xf.reshape(ep, T // ep, shape[-1])
+    tok = None if token_ids is None else token_ids.reshape(ep, T // ep)
+    tv = None if token_valid is None else token_valid.reshape(ep, T // ep)
+    wr = params["router"]["w"]
+    experts = params["experts"]
+    E = moe.n_experts
+    e_loc = E // ep
+
+    def local_shard(wr, experts_loc, xf, my_shard, token_ids, token_valid):
+        T = xf.shape[0]
+        lo = my_shard * e_loc
+        rr = R.route(wr, xf, moe, rng=_shard_rng(rng, my_shard),
+                     is_training=True, token_ids=token_ids,
+                     expert_lo=lo, n_local=e_loc)
+        rr, valid = _local_adjust(rr, moe, lo, e_loc)
+        if token_valid is not None:
+            valid = valid & token_valid.reshape(-1, 1)
+        rr_local = rr._replace(topk_idx=rr.topk_idx - lo)
+        cap = min(R.capacity(T, e_loc, moe.top_k, moe.capacity_factor), T)
+        info = R.dispatch_info(rr_local, e_loc, cap, valid=valid)
+        buf = R.dispatch(xf, info, e_loc, cap)
+        out = _expert_ffn(experts_loc, buf, cfg, None)
+        y = R.combine(out, info)
+        return y, _local_aux(rr, info, moe, T)
+
+    def shard_local(my, xl, tl, tvl):
+        ex_loc = jax.tree.map(lambda w: jax.lax.dynamic_slice_in_dim(
+            w, my * e_loc, e_loc, axis=0), experts)
+        return local_shard(wr, ex_loc, xl, my, tl, tvl)
+
+    ys, auxs = jax.vmap(
+        shard_local, in_axes=(0, 0, 0 if tok is not None else None,
+                              0 if tv is not None else None))(
+        jnp.arange(ep), xs, tok, tv)
+    return (ys.reshape(T, -1).reshape(shape),
+            jax.tree.map(lambda a: a.mean(0), auxs))
+
+
+@pytest.mark.parametrize("tokens", ["absent", "given"])
+@pytest.mark.parametrize("local_combine", ["prob", "one"])
+@pytest.mark.parametrize("k", [1, 2])
+@pytest.mark.parametrize("ep", [1, 2, 4])
+def test_oracle_local_ffn_outside_vmap_is_bitwise_the_vmapped_one(
+        ep, k, local_combine, tokens):
+    """The oracle's Gate-Drop branch runs one expert FFN over all experts
+    outside the virtual-shard vmap; its outputs, every aux entry and the
+    parameter and input gradients are bitwise those of the per-shard FFN
+    it replaced."""
+    cfg = ModelConfig(d_model=64, d_ff=128, vocab=64, moe=MoEConfig(
+        n_experts=8, top_k=k, d_ff_expert=128,
+        gating_dropout=GatingDropoutConfig(mode="gate_drop", rate=0.3,
+                                           local_combine=local_combine)))
+    p = init_moe_params(jax.random.PRNGKey(0), cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 64))
+    ct = jax.random.normal(jax.random.PRNGKey(2), x.shape)
+    rng = jax.random.PRNGKey(3)
+    tok = valid = None
+    if tokens == "given":
+        tok = jax.random.randint(jax.random.PRNGKey(4), x.shape[:2], 0, 64)
+        valid = jax.random.bernoulli(jax.random.PRNGKey(5), 0.8, x.shape[:2])
+
+    def run(layer):
+        def loss(p, x):
+            y, aux = layer(p, x)
+            return (y * ct).sum() + aux["router_entropy"], (y, aux)
+        return jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))(p, x)
+
+    new = run(lambda p, x: moe_oracle(p, x, cfg, ep=ep, rng=rng,
+                                      decision=True, token_ids=tok,
+                                      token_valid=valid))
+    old = run(lambda p, x: _vmapped_ffn_local(p, x, cfg, ep, rng, tok, valid))
+    (g_new, (y_new, aux_new)), (g_old, (y_old, aux_old)) = new, old
+    np.testing.assert_array_equal(np.asarray(y_new), np.asarray(y_old))
+    assert sorted(aux_new) == sorted(aux_old)
+    for name in aux_old:
+        np.testing.assert_array_equal(np.asarray(aux_new[name]),
+                                      np.asarray(aux_old[name]), err_msg=name)
+    for a, b in zip(jax.tree.leaves(g_new), jax.tree.leaves(g_old)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

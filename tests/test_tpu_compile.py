@@ -144,3 +144,47 @@ def test_encdec_gate_drop_chunk_has_no_alltoall_on_v5e_mesh(topo,
     a2a = {dec: chunk.lower(state, batch, dec).compile().as_text().count(
         "all-to-all(") for dec in (False, True)}
     assert a2a[False] > 0 and a2a[True] == 0, a2a
+
+
+def test_one_chip_gate_drop_chunk_copies_no_expert_leaf(one_chip,
+                                                       no_compile_cache):
+    """On one described v5e chip neither host_cond step program copies an
+    expert leaf of the train state. The oracle's Gate-Drop branch once ran
+    its expert FFN under the virtual-shard vmap: the weight gradient came
+    out transposed, Adam's update followed it, and every expert parameter
+    and moment was copied into that layout and back, 24 copies a dropped
+    step."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.configs.base import TrainConfig
+    from repro.models import init_model
+    from repro.training import init_train_state, make_chunk_step
+
+    base = get_config("zcode-m3-base")
+    n_exp = 8
+    cfg = dataclasses.replace(
+        base, d_model=128, n_heads=2, n_kv_heads=2, d_ff=256, vocab=1024,
+        n_layers=2,
+        encdec=dataclasses.replace(base.encdec, n_encoder_layers=2),
+        moe=dataclasses.replace(
+            base.moe, n_experts=n_exp,
+            gating_dropout=dataclasses.replace(
+                base.moe.gating_dropout, mode="gate_drop", rate=0.3,
+                strategy="host_cond")))
+    tc = TrainConfig(steps=1)
+    shape = jax.eval_shape(
+        lambda: init_train_state(init_model(jax.random.PRNGKey(0), cfg), tc))
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        shape)
+    batch = {k: jax.ShapeDtypeStruct((1, 8, 32), F32 if k == "loss_mask"
+                                     else I32, sharding=one_chip)
+             for k in ("enc_tokens", "tokens", "labels", "loss_mask")}
+    chunk = make_chunk_step(cfg, tc)
+    leaf_copy = re.compile(rf"= f32\[\d+,{n_exp},\d+,\d+\]\{{[^}}]*\}} copy\(")
+    copies = {dec: len(leaf_copy.findall(
+        chunk.lower(state, batch, dec).compile().as_text()))
+        for dec in (False, True)}
+    assert copies == {False: 0, True: 0}, copies
