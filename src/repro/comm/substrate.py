@@ -270,11 +270,12 @@ class _FactoredTopo:
 
 class Transport:
     """One routed layer's wire. ``dispatch``: per-shard (E, cap, d) ->
-    (E/ep, ep*cap, d); ``combine`` is the exact inverse; ``vdispatch``/
-    ``vcombine`` are the oracle's stacked-tensor emulation
-    (ep, E, cap, d) <-> (E, ep*cap, d). ``roundtrip`` applies only the
-    payload wire transform (quant->dequant, no movement) — the ep=1
-    kernel pipeline uses it so backend choice never changes numerics."""
+    (E/ep, ep*cap, d); ``combine`` is the exact inverse; both run under
+    the ``exchange`` name scope. ``vdispatch``/``vcombine`` are the
+    oracle's stacked-tensor emulation (ep, E, cap, d) <-> (E, ep*cap, d).
+    ``roundtrip`` applies only the payload wire transform (quant->dequant,
+    no movement) — the ep=1 kernel pipeline uses it so backend choice
+    never changes numerics."""
 
     def __init__(self, comm: CommConfig, env: CommEnv, topo):
         self.comm, self.env, self.topo = comm, env, topo
@@ -293,6 +294,12 @@ class Transport:
             self.vdispatch = topo.vdispatch
             self.vcombine = topo.vcombine
             self.roundtrip = lambda x: x
+        # every op of a real exchange, the collectives and the packing,
+        # quantizing and reshaping around them, forward and backward,
+        # carries ``exchange`` in its ``op_name``, which a profiler trace
+        # reads back
+        self.dispatch = jax.named_scope("exchange")(self.dispatch)
+        self.combine = jax.named_scope("exchange")(self.combine)
 
     def pipelined(self, buf: jax.Array, fn: Callable) -> jax.Array:
         """The §14 transport contract: run ``dispatch -> fn -> combine``

@@ -188,3 +188,73 @@ def test_one_chip_gate_drop_chunk_copies_no_expert_leaf(one_chip,
         chunk.lower(state, batch, dec).compile().as_text()))
         for dec in (False, True)}
     assert copies == {False: 0, True: 0}, copies
+
+
+@pytest.mark.parametrize("dropped", [False, True], ids=["routed", "dropped"])
+def test_ep4_chunk_exchange_is_scoped_and_dropped_copies_no_expert_leaf(
+        topo, no_compile_cache, dropped):
+    """zcode-m3-big's layout on a described 2x2 mesh, 16 experts over 4
+    chips (4 each), host_cond Gate-Drop: every all-to-all of the routed
+    step program carries the ``exchange`` scope under ``moe`` in its
+    ``op_name``, so a profiler trace can name and time it; the dropped
+    program holds no all-to-all and, like the one-chip oracle's, copies no
+    expert leaf of the train state. A TPU trace names each op by its
+    instruction text without the metadata, and ``bench/trace_reduce.py``
+    times the ops whose name holds ``all-to-all``: only the all-to-all
+    instructions match, since an op that reads one names it
+    ``%all_to_all.N``."""
+    import dataclasses
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.configs import get_config
+    from repro.configs.base import TrainConfig
+    from repro.core.moe import ParallelContext
+    from repro.models import init_model
+    from repro.training import (init_train_state, make_chunk_step,
+                                train_state_sharding)
+
+    base = get_config("zcode-m3-big")
+    n_exp, chips = 16, 4
+    cfg = dataclasses.replace(
+        base, d_model=128, n_heads=2, n_kv_heads=2, d_ff=256, vocab=1024,
+        n_layers=2,
+        encdec=dataclasses.replace(base.encdec, n_encoder_layers=2),
+        moe=dataclasses.replace(
+            base.moe, n_experts=n_exp,
+            gating_dropout=dataclasses.replace(
+                base.moe.gating_dropout, mode="gate_drop", rate=0.3,
+                strategy="host_cond")))
+    mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
+    ctx = ParallelContext(mesh=mesh)
+    tc = TrainConfig(steps=1)
+    shape = jax.eval_shape(
+        lambda: init_train_state(init_model(jax.random.PRNGKey(0), cfg), tc))
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        shape, train_state_sharding(cfg, tc, ctx))
+    rows = NamedSharding(mesh, P(None, "data"))
+    batch = {k: jax.ShapeDtypeStruct((1, 16, 32), F32 if k == "loss_mask"
+                                     else I32, sharding=rows)
+             for k in ("enc_tokens", "tokens", "labels", "loss_mask")}
+    text = make_chunk_step(cfg, tc, ctx).lower(
+        state, batch, dropped).compile().as_text()
+    a2a = [ln for ln in text.splitlines()
+           if re.search(r"= \S+ all-to-all(?:-start)?\(", ln)]
+    if dropped:
+        assert a2a == []
+        # a chip's expert leaves: f32[1, 4, 128, 256] or [1, 4, 256, 128]
+        leaf_copy = re.compile(rf"= f32\[1,{n_exp // chips},(?:128,256|"
+                               rf"256,128)\]\{{[^}}]*\}} copy\(")
+        assert leaf_copy.findall(text) == []
+    else:
+        assert a2a
+        bare = lambda ln: re.sub(r", metadata=\{.*\}", "", ln)  # noqa: E731
+        assert [ln for ln in map(bare, text.splitlines())
+                if "all-to-all" in ln] == [bare(ln) for ln in a2a]
+        names = [re.search(r'op_name="([^"]*)"', ln) for ln in a2a]
+        assert all(m and re.search(r"(^|/)moe/(.*/)?exchange/", m.group(1))
+                   for m in names), [m and m.group(1) for m in names]
