@@ -10,8 +10,8 @@ Two measurements, both warmed and interleaved (min-of-reps, so a single
 scheduler hiccup on one variant cannot fake an overhead):
 
   train — the table7 train config (reduced zcode-m3-base, gate_drop 0.3,
-      traced_cond). Timed at the Trainer._dispatch level: one scan-fused
-      chunk per rep, baseline = (tracer disabled, metrics_frame=False) vs
+      traced_cond). Timed at the Trainer._dispatch/_fetch level: one
+      scan-fused chunk per rep, enqueued and fetched, baseline = (tracer disabled, metrics_frame=False) vs
       instrumented = (tracer enabled, metrics_frame=True).
   serve — a table8-style backlogged mixed trace through
       ContinuousScheduler, baseline = disabled tracer vs instrumented =
@@ -65,8 +65,15 @@ def _trainer(cfg, *, frame: bool, traced: bool):
                  strategy="traced_cond", log=None,
                  tracer=Tracer(enabled=traced))
     stacked = stack_batches(tr.batch_fn, 0, CHUNK)
-    tr._dispatch((0, CHUNK), stacked)          # compile off the clock
+    _chunk(tr, 0, stacked)                     # compile off the clock
     return tr, stacked
+
+
+def _chunk(tr, s, stacked):
+    """Enqueue the chunk [s, s + CHUNK) and fetch its metrics: one whole
+    chunk, its host-device sync included."""
+    e = s + CHUNK
+    return tr._fetch(s, e, tr._dispatch([(s, e, None)], stacked, 0))
 
 
 def bench_train(reps: int = 5):
@@ -77,10 +84,10 @@ def bench_train(reps: int = 5):
     t_off, t_on = [], []
     for _ in range(reps):                      # interleaved pairs
         t0 = time.perf_counter()
-        base._dispatch((0, CHUNK), b_batch)
+        _chunk(base, 0, b_batch)
         t_off.append(time.perf_counter() - t0)
         t0 = time.perf_counter()
-        inst._dispatch((0, CHUNK), i_batch)
+        _chunk(inst, 0, i_batch)
         t_on.append(time.perf_counter() - t0)
     return min(t_off), min(t_on)
 
@@ -92,9 +99,8 @@ def check_train_bitwise():
     ms = {}
     for frame in (False, True):
         tr, stacked = _trainer(cfg, frame=frame, traced=False)
-        ms[frame] = tr._dispatch((CHUNK, 2 * CHUNK),
-                                 stack_batches(tr.batch_fn, CHUNK,
-                                               2 * CHUNK))
+        ms[frame] = _chunk(tr, CHUNK,
+                           stack_batches(tr.batch_fn, CHUNK, 2 * CHUNK))
     loss_eq = np.array_equal(ms[False]["loss"], ms[True]["loss"])
     acc_eq = np.array_equal(ms[False]["acc"], ms[True]["acc"])
     frame_keys = set(ms[True]) - set(ms[False])

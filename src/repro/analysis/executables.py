@@ -417,13 +417,22 @@ def _trainer_scenario():
     trainer = Trainer(cfg, tc, lambda i: task.sample_batch(i, 2),
                       chunk=2, strategy="traced_cond", prefetch=False,
                       log=None, tracer=Tracer(enabled=True))
-    fetch = lambda lo, hi: stack_batches(trainer.batch_fn, lo, hi)
-    trainer._dispatch((0, 2), fetch(0, 2))       # warmup: compile outside
+    def enqueue(lo, hi, in_flight):
+        return trainer._dispatch([(lo, hi, None)],
+                                 stack_batches(trainer.batch_fn, lo, hi),
+                                 in_flight)
+
+    trainer._fetch(0, 2, enqueue(0, 2, 0))       # warmup: compile outside
     evs = []
     with guard_host_transfers(events=evs):
         before = jit_cache_sizes([trainer.chunk_fn])
-        trainer._dispatch((2, 4), fetch(2, 4))
-        trainer._dispatch((4, 6), fetch(4, 6))
+        # run()'s steady state: chunk i+1 enqueued before i is fetched
+        first = enqueue(2, 4, 0)
+        second = enqueue(4, 6, 1)
+        trainer._fetch(2, 4, first)
+        third = enqueue(6, 8, 1)
+        trainer._fetch(4, 6, second)
+        trainer._fetch(6, 8, third)
         after = jit_cache_sizes([trainer.chunk_fn])
     return {"events": evs,
             "cache_sizes": [("chunk_fn", before[0], after[0])]}

@@ -22,7 +22,8 @@ because Gating Dropout alters routing, not activation magnitudes (paper §3).
 """
 from __future__ import annotations
 
-from typing import Union
+import functools
+from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -54,27 +55,37 @@ def drop_decision_host(cfg: GatingDropoutConfig, seed: int, step: int, *,
         jax.random.bernoulli(decision_key(seed, step), cfg.rate)))
 
 
-@jax.jit
-def _decisions_batch(seed: jax.Array, steps: jax.Array,
-                     rate: jax.Array) -> jax.Array:
+# steps per batched draw: every span is drawn in blocks of this length, so
+# one compiled program serves spans of any length
+DRAW_BLOCK = 256
+
+
+@functools.partial(jax.jit, static_argnums=(3,))
+def _decisions_batch(seed: jax.Array, start: jax.Array, rate: jax.Array,
+                     n: int) -> jax.Array:
     key = jax.random.PRNGKey(seed ^ 0x6A7E_D0)
     return jax.vmap(
         lambda s: jax.random.bernoulli(jax.random.fold_in(key, s), rate)
-    )(steps)
+    )(start + jnp.arange(n, dtype=jnp.int32))
 
 
-def drop_decisions_host(cfg: GatingDropoutConfig, seed: int, start: int,
-                        stop: int, *, is_training: bool = True) -> np.ndarray:
-    """Concrete bools for steps [start, stop) in ONE jitted dispatch —
+def drop_decisions_host(cfg: Optional[GatingDropoutConfig], seed: int,
+                        start: int, stop: int, *,
+                        is_training: bool = True) -> np.ndarray:
+    """Concrete bools for steps [start, stop), drawn on the device in
+    blocks of ``DRAW_BLOCK`` steps and fetched by ONE ``device_get`` —
     bitwise the per-step ``drop_decision_host`` draws (same (seed, step)
-    fold, vmapped). The scan-fused Trainer's host_cond path uses this so
-    drawing a chunk's bits never costs per-step eager dispatches."""
+    fold, vmapped). The scan-fused Trainer's host_cond path draws a whole
+    ``run()``'s bits with it, before the first step; the fixed block
+    length means a span of a new length compiles nothing. ``cfg`` None
+    (no Gating Dropout) draws all False, as a disabled ``cfg`` does."""
     n = max(stop - start, 0)
-    if not is_training or not cfg.enabled or n == 0:
+    if not is_training or cfg is None or not cfg.enabled or n == 0:
         return np.zeros(n, bool)
-    # explicit sync: drawing the chunk's bits host-side IS the strategy
-    return jax.device_get(_decisions_batch(seed, jnp.arange(start, stop),
-                                           cfg.rate))
+    blocks = [_decisions_batch(seed, lo, cfg.rate, DRAW_BLOCK)
+              for lo in range(start, stop, DRAW_BLOCK)]
+    # explicit sync: drawing the bits host-side IS the strategy
+    return np.concatenate(jax.device_get(blocks))[:n]
 
 
 def expected_alltoall_fraction(cfg: GatingDropoutConfig) -> float:

@@ -10,6 +10,22 @@ accumulated on-device and fetched once per chunk, fed by the
 double-buffered background prefetcher (``repro.data.prefetch``) over
 vectorized batch synthesis (``repro.data.pipeline``).
 
+Host-device syncs — ``run()`` keeps one chunk in flight, so nothing the
+host waits on stands between the enqueue of one chunk and the next:
+
+  * one explicit ``jax.device_get`` of the metrics per chunk, lagged by
+    one chunk: chunk i's metrics are fetched after chunk i+1 is enqueued,
+    so the next batch's put and the record work overlap the step in
+    flight;
+  * drained (nothing left in flight) before ``eval_fn`` runs, and at the
+    end of ``run()``, so the state ``eval_fn`` sees, the checkpoint and
+    ``state`` / ``history`` after ``run()`` are exactly the unpipelined
+    loop's;
+  * host_cond's consensus bits for the whole of ``run()`` drawn in one
+    batched call before the first chunk is enqueued;
+  * a record's ``time_s`` / ``tok_s`` are stamped when its chunk's
+    metrics are fetched.
+
 Decision semantics — both bitwise-faithful to K legacy per-step calls
 (asserted in ``tests/test_trainer.py``):
 
@@ -17,8 +33,9 @@ Decision semantics — both bitwise-faithful to K legacy per-step calls
       length-K vector: ``vmap`` of ``drop_decision`` over
       (seed, absolute_step) — the identical fold the per-step path uses,
       so the bits agree bitwise and stay traced (``lax.cond`` per step).
-  host_cond  — the host draws the K bits (``drop_decision_host``), splits
-      the chunk into MAXIMAL SAME-DECISION RUNS, and dispatches each run
+  host_cond  — the host draws the bits (``drop_decisions_host``, once per
+      ``run()``), splits each chunk into MAXIMAL SAME-DECISION RUNS, and
+      dispatches each run
       to a scan-fused executable whose decision is a static argument:
       the dropped run executable still contains zero all-to-alls
       (``tests/test_trainer.py::test_dropped_chunk_executable_has_no_alltoall``).
@@ -118,15 +135,11 @@ def make_chunk_step(cfg: ModelConfig, tc: TrainConfig,
     return chunk_fn
 
 
-def same_decision_runs(gd, seed: int, lo: int, hi: int
-                       ) -> List[Tuple[int, int, bool]]:
-    """Split [lo, hi) into maximal runs of equal host-drawn consensus bits:
-    [(start, stop, decision), ...] covering the span in order. The bits
-    come from ONE batched draw (``drop_decisions_host``), not per-step
-    eager dispatches."""
-    if gd is None or not gd.enabled:
-        return [(lo, hi, False)]
-    decs = [bool(d) for d in drop_decisions_host(gd, seed, lo, hi)]
+def split_runs(bits: np.ndarray, lo: int) -> List[Tuple[int, int, bool]]:
+    """Maximal runs of equal consensus bits, ``bits[j]`` being step
+    ``lo + j``'s: [(start, stop, decision), ...] covering the span in
+    order."""
+    decs = [bool(d) for d in bits]
     runs, i = [], 0
     while i < len(decs):
         j = i
@@ -135,6 +148,14 @@ def same_decision_runs(gd, seed: int, lo: int, hi: int
         runs.append((lo + i, lo + j, decs[i]))
         i = j
     return runs
+
+
+def same_decision_runs(gd, seed: int, lo: int, hi: int
+                       ) -> List[Tuple[int, int, bool]]:
+    """Split [lo, hi) into maximal runs of equal host-drawn consensus bits.
+    The bits come from ONE batched draw (``drop_decisions_host``), not
+    per-step eager dispatches."""
+    return split_runs(drop_decisions_host(gd, seed, lo, hi), lo)
 
 
 class Trainer:
@@ -150,7 +171,7 @@ class Trainer:
     strategy : "traced_cond" | "host_cond" | None (None = follow
         ``cfg.moe.gating_dropout.strategy``; DESIGN.md §5).
     eval_fn : (state, step) -> dict merged into that step's history
-        record; runs at chunk ends only.
+        record; runs at chunk ends only, with no chunk in flight.
     log : callable for per-record lines (default: print as JSON); None
         disables printing (history is still returned).
     """
@@ -245,16 +266,15 @@ class Trainer:
         return spans
 
     # ---- run --------------------------------------------------------------
-    def _dispatch(self, span: Tuple[int, int], stacked: Dict
-                  ) -> Dict[str, np.ndarray]:
-        """Run one chunk; returns per-step metrics stacked over the span
-        (the chunk's ONLY host-device sync, via an explicit
-        jax.device_get — the analysis.hostsync guard flags implicit
-        pulls inside steady-state ticks). Each host phase has a span:
-        ``chunk.decide`` (host_cond's consensus draw), then per
-        same-decision run ``chunk.put`` and ``chunk.execute`` (the
-        enqueue), then ``chunk.fetch``."""
-        s, e = span
+    def _dispatch(self, runs: List[Tuple[int, int, Optional[bool]]],
+                  stacked: Dict, in_flight: int) -> List[Dict]:
+        """Enqueue one chunk, given as its same-decision runs (one run with
+        decision None under traced_cond), and return its per-run metrics
+        unfetched: ``_fetch`` is the chunk's one host-device sync. Per
+        run, ``chunk.put`` then ``chunk.execute`` (the enqueue), whose arg
+        ``in_flight`` is the number of chunks enqueued and not yet fetched
+        when this one was."""
+        s, e = runs[0][0], runs[-1][1]
         tr = self.tracer
         # jit-retrace detection: _cache_size is host-only introspection,
         # read only when tracing (it never syncs, but stays off the
@@ -266,22 +286,25 @@ class Trainer:
                 return jnp.asarray(v)
             return jax.device_put(v, self.batch_sharding)
 
-        if self.strategy == "traced_cond":
-            runs = [(s, e, None)]
-        else:
-            with tr.span("chunk.decide", start=s, stop=e):
-                runs = same_decision_runs(self.gd, self.tc.seed, s, e)
         parts = []
         for rs, re, dec in runs:
             with tr.span("chunk.put", start=rs, stop=re):
                 sub = {k: put(v[rs - s:re - s]) for k, v in stacked.items()}
             with tr.span("chunk.execute", start=rs, stop=re,
-                         decision="traced" if dec is None else bool(dec)):
+                         decision="traced" if dec is None else bool(dec),
+                         in_flight=in_flight):
                 self.state, m = self.chunk_fn(self.state, sub, dec)
             parts.append(m)
         if tr.enabled and self.chunk_fn._cache_size() > n0:
             tr.instant("jit_retrace", fn="chunk_fn", start=s, stop=e)
-        with tr.span("chunk.fetch", start=s, stop=e):
+        return parts
+
+    def _fetch(self, s: int, e: int, parts: List[Dict]
+               ) -> Dict[str, np.ndarray]:
+        """The chunk [s, e)'s per-step metrics stacked over the span, by an
+        explicit ``jax.device_get`` (the analysis.hostsync guard flags
+        implicit pulls inside steady-state ticks); waits for the chunk."""
+        with self.tracer.span("chunk.fetch", start=s, stop=e):
             parts = jax.device_get(parts)
             return {k: np.concatenate([p[k] for p in parts])
                     for k in parts[0]}
@@ -289,13 +312,13 @@ class Trainer:
     def _record(self, s: int, e: int, ms: Dict[str, np.ndarray], el: float,
                 tok_s: float, rec_steps: set, eval_steps: set) -> None:
         """History records of the chunk's recorded steps, from its fetched
-        metrics; ``el`` is the chunk-boundary time since the run began."""
+        metrics; ``el`` is the time from the run's start to the fetch."""
         for i in range(s, e):
             if i not in rec_steps:
                 continue
             j = i - s
             # tok_s pairs the CHUNK-complete token count with the
-            # chunk-boundary timestamp (el) — same convention as
+            # chunk's fetch timestamp (el) — same convention as
             # time_s; pro-rating tokens to step i against el would
             # understate mid-chunk throughput
             rec = {"step": i, "loss": float(ms["loss"][j]),
@@ -332,7 +355,10 @@ class Trainer:
                 self.log(json.dumps(rec))
 
     def run(self) -> Tuple[Any, List[Dict]]:
-        tc = self.tc
+        """Train [start_step, tc.steps), keeping one chunk in flight (module
+        docstring); returns ``(state, history)`` with every chunk fetched
+        and recorded."""
+        tc, tr = self.tc, self.tracer
         spans = self.schedule()
         fetch = lambda span: stack_batches(self.batch_fn, *span)  # noqa: E731
         it = (Prefetcher(fetch, spans, self.prefetch_depth,
@@ -340,21 +366,43 @@ class Trainer:
               if self.prefetch else map(fetch, spans))
         rec_steps, eval_steps = self._record_steps(), self._eval_steps()
         tokens_done, t0 = 0, monotonic()
+        lo, bits = self.start_step, None
+        if self.strategy == "host_cond" and spans:
+            with tr.span("chunk.decide", start=lo, stop=tc.steps,
+                         steps=tc.steps - lo):
+                bits = drop_decisions_host(self.gd, tc.seed, lo, tc.steps)
+
+        def retire(s, e, parts, tokens):
+            nonlocal tokens_done
+            ms = self._fetch(s, e, parts)
+            with tr.span("chunk.record", start=s, stop=e):
+                el = monotonic() - t0
+                tokens_done += tokens
+                self._record(s, e, ms, el, tokens_done / max(el, 1e-9),
+                             rec_steps, eval_steps)
+
+        pending = None        # the chunk enqueued and not yet fetched
         try:
             for span, stacked in zip(spans, it):
                 s, e = span
                 tok_per_step = sum(int(stacked[k][0].size)
                                    for k in TOKEN_KEYS if k in stacked)
                 # ``positions`` counts padded positions, not tokens
-                with self.tracer.span("train_chunk", start=s, stop=e,
-                                      strategy=self.strategy,
-                                      positions=(e - s) * tok_per_step):
-                    ms = self._dispatch(span, stacked)
-                    with self.tracer.span("chunk.record", start=s, stop=e):
-                        el = monotonic() - t0
-                        tokens_done += (e - s) * tok_per_step
-                        self._record(s, e, ms, el, tokens_done / max(el, 1e-9),
-                                     rec_steps, eval_steps)
+                with tr.span("train_chunk", start=s, stop=e,
+                             strategy=self.strategy,
+                             positions=(e - s) * tok_per_step):
+                    runs = ([(s, e, None)] if bits is None
+                            else split_runs(bits[s - lo:e - lo], s))
+                    parts = self._dispatch(runs, stacked,
+                                           in_flight=int(pending is not None))
+                    if pending is not None:
+                        retire(*pending)
+                    pending = (s, e, parts, (e - s) * tok_per_step)
+                    # drain: eval_fn, the checkpoint and the caller read
+                    # the state after this chunk
+                    if e - 1 in eval_steps or e == tc.steps:
+                        retire(*pending)
+                        pending = None
         finally:
             if isinstance(it, Prefetcher):
                 it.close()

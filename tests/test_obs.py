@@ -162,6 +162,16 @@ def test_metrics_frame_bitwise_non_interference():
     assert "router_entropy" in extra and "expert_load" in extra
 
 
+def test_table12_train_chunk_is_frame_bitwise():
+    """benchmarks/table12_obs.py (the observability overhead gate) drives
+    the Trainer's enqueue/fetch pair directly: its train chunk must run,
+    and the MetricsFrame must leave the loss/acc stream bitwise as is."""
+    from benchmarks.table12_obs import check_train_bitwise
+    bitwise, frame_keys = check_train_bitwise()
+    assert bitwise
+    assert frame_keys == ["expert_load", "gate_dropped", "router_entropy"]
+
+
 def test_metrics_frame_typed_view():
     """from_metrics builds only from a complete frame; imbalance and
     summary math behave on known inputs."""
@@ -298,6 +308,41 @@ def test_trainer_instrumentation_coverage(strategy):
                 "gate_dropped"} <= set(rec)
     # the exported trace of a real run is loadable Chrome JSON
     json.dumps(tracer.export())
+
+
+def test_trainer_in_flight_counter_and_one_decide_per_run():
+    """host_cond with an enabled tracer: every ``chunk.execute`` carries
+    ``in_flight``, 0 on a run's first chunk and on the first chunk after
+    each drain (at eval steps) and 1 on every other; ``chunk.decide``
+    appears once per run() with ``steps`` the run's length."""
+    cfg = _cfg()
+    tc = TrainConfig(lr=1e-3, warmup_steps=2, steps=8, seed=0)
+    task = SyntheticLM(LMTaskConfig(vocab=cfg.vocab, seq_len=16))
+    tracer = Tracer(enabled=True)
+    trainer = Trainer(cfg, tc, lambda i: task.sample_batch(i, 4), chunk=2,
+                      strategy="host_cond", eval_every=5,
+                      eval_fn=lambda s, i: {}, log=None, tracer=tracer)
+    spans = trainer.schedule()
+    assert spans == [(0, 1), (1, 3), (3, 5), (5, 6), (6, 8)]
+    trainer.run()
+    trainer.start_step = 8
+    trainer.tc = dataclasses.replace(tc, steps=12)
+    trainer.run()
+    assert trainer.schedule() == [(8, 10), (10, 11), (11, 12)]
+    spans += trainer.schedule()
+    ev = [e for e in tracer.events if e[0] == "X"]
+    decide = [e[5] for e in ev if e[1] == "chunk.decide"]
+    assert [(a["start"], a["stop"], a["steps"]) for a in decide] == [
+        (0, 8, 8), (8, 12, 4)]
+    flight = {}
+    for e in ev:
+        if e[1] == "chunk.execute":
+            c = next(sp for sp in spans if sp[0] <= e[5]["start"] < sp[1])
+            flight.setdefault(c, set()).add(e[5]["in_flight"])
+    # drains after the eval steps 0, 5, 7, 10, 11; 7 and 11 end a run
+    assert flight == {(0, 1): {0}, (1, 3): {0}, (3, 5): {1}, (5, 6): {1},
+                      (6, 8): {0}, (8, 10): {0}, (10, 11): {1},
+                      (11, 12): {0}}
 
 
 # the name scopes a profiler trace reads the train step's layers by

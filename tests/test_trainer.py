@@ -110,6 +110,140 @@ def test_trainer_end_to_end_bitwise(strategy):
             assert np.isfinite(rec[k]), (rec, k)
 
 
+def _legacy_states(cfg, tc, batch_fn, steps, strategy):
+    """Every state of the legacy loop: [state after step i for i < steps],
+    each pulled to host, with step i's loss."""
+    gd = cfg.moe.gating_dropout
+    step = make_train_step(cfg, tc)
+    s = init_train_state(init_model(jax.random.PRNGKey(tc.seed), cfg), tc)
+    out = []
+    for i in range(steps):
+        b = {k: jnp.asarray(v) for k, v in batch_fn(i).items()}
+        dec = (drop_decision_host(gd, tc.seed, i)
+               if strategy == "host_cond" else None)
+        s, m = step(s, b, dec)
+        out.append((jax.device_get(s), float(m["loss"])))
+    return out
+
+
+def test_host_cond_run_keeps_one_chunk_in_flight(monkeypatch):
+    """A host_cond run over four chunks: chunk i+1 is enqueued before chunk
+    i's metrics are fetched, the last chunk is fetched before run()
+    returns, and the consensus bits are drawn once per run(), before the
+    first enqueue."""
+    import repro.core.gating_dropout as G
+    cfg = _cfg()
+    tc = TrainConfig(lr=1e-3, warmup_steps=2, seed=3, steps=8)
+    _, batch_fn = _task_and_batch_fn(cfg)
+    tr = Trainer(cfg, tc, batch_fn, chunk=2, strategy="host_cond", log=None)
+    log = []
+    inner, draw, get = tr.chunk_fn, G._decisions_batch, jax.device_get
+    at = [0]
+
+    def chunk_fn(state, batches, decision):
+        k = jax.tree.leaves(batches)[0].shape[0]
+        log.append(("enqueue", at[0], at[0] + k))
+        at[0] += k
+        return inner(state, batches, decision)
+
+    def decisions_batch(*a):
+        log.append(("draw",))
+        return draw(*a)
+
+    def device_get(x):
+        if isinstance(x, list) and x and isinstance(x[0], dict):
+            log.append(("fetch", sum(len(p["loss"]) for p in x)))
+        return get(x)
+
+    tr.chunk_fn = chunk_fn
+    monkeypatch.setattr(G, "_decisions_batch", decisions_batch)
+    monkeypatch.setattr(jax, "device_get", device_get)
+    tr.run()
+    runs = [(a, b) for kind, *ab in log if kind == "enqueue"
+            for a, b in [ab]]
+    assert runs[0][0] == 0 and runs[-1][1] == 8
+    assert log[0] == ("draw",) and log.count(("draw",)) == 1
+    # chunk c's position in the log: its last enqueue and its fetch
+    spans = tr.schedule()
+    assert spans == [(0, 2), (2, 4), (4, 6), (6, 8)]
+    fetches = [i for i, ev in enumerate(log) if ev[0] == "fetch"]
+    assert [log[i][1] for i in fetches] == [2, 2, 2, 2]
+
+    def last_enqueue(span):
+        return max(i for i, ev in enumerate(log)
+                   if ev[0] == "enqueue" and span[0] <= ev[1] < span[1])
+
+    for c in range(len(spans) - 1):
+        assert last_enqueue(spans[c]) < last_enqueue(spans[c + 1]) \
+            < fetches[c], log
+    assert fetches[-1] == len(log) - 1
+    # a second run(): one more draw, again before its first enqueue
+    n = len(log)
+    tr.start_step, tr.tc = 8, dataclasses.replace(tc, steps=12)
+    tr.run()
+    assert log[n] == ("draw",) and log.count(("draw",)) == 2
+
+
+@pytest.mark.parametrize("strategy", ["traced_cond", "host_cond"])
+def test_eval_fn_sees_the_legacy_state_mid_run(strategy):
+    """With eval steps inside the run and chunks in flight between them,
+    eval_fn(state, i) receives bitwise the legacy loop's state after step
+    i, and every recorded loss is the legacy step's."""
+    cfg = _cfg()
+    tc = TrainConfig(lr=1e-3, warmup_steps=2, seed=3, steps=10)
+    _, batch_fn = _task_and_batch_fn(cfg)
+    seen = {}
+
+    def eval_fn(state, i):
+        seen[i] = jax.device_get(state)
+        return {"evaluated": 1.0}
+
+    tr = Trainer(cfg, tc, batch_fn, chunk=2, strategy=strategy,
+                 eval_every=4, eval_fn=eval_fn, log=None, log_every=1)
+    # chunks (1, 3) and (5, 7) are in flight when the next is enqueued
+    assert tr.schedule() == [(0, 1), (1, 3), (3, 5), (5, 7), (7, 9),
+                             (9, 10)]
+    _, history = tr.run()
+    legacy = _legacy_states(cfg, tc, batch_fn, tc.steps, strategy)
+    assert sorted(seen) == [0, 4, 8, 9]
+    for i, state in seen.items():
+        _assert_bitwise(state, legacy[i][0])
+    assert [r["step"] for r in history] == list(range(tc.steps))
+    for r in history:
+        assert r["loss"] == legacy[r["step"]][1], r
+        assert ("evaluated" in r) == (r["step"] in seen)
+
+
+@pytest.mark.parametrize("strategy", ["traced_cond", "host_cond"])
+def test_checkpoint_and_resume_bitwise_with_chunks_in_flight(tmp_path,
+                                                             strategy):
+    """The checkpoint written at the end of a pipelined run() holds the
+    legacy loop's state after its last step, and a run resumed from it
+    ends on the legacy state too."""
+    from repro.checkpoint import restore_checkpoint
+    cfg = _cfg()
+    _, batch_fn = _task_and_batch_fn(cfg)
+
+    def make(steps):
+        tc = TrainConfig(lr=1e-3, warmup_steps=2, seed=3, steps=steps)
+        return Trainer(cfg, tc, batch_fn, chunk=2, strategy=strategy,
+                       ckpt_dir=str(tmp_path), log=None)
+
+    tc = TrainConfig(lr=1e-3, warmup_steps=2, seed=3, steps=9)
+    legacy = _legacy_states(cfg, tc, batch_fn, tc.steps, strategy)
+    first = make(5)
+    state, _ = first.run()                  # chunks (0,2) (2,4) (4,5)
+    _assert_bitwise(state, legacy[4][0])
+    saved, meta = restore_checkpoint(str(tmp_path), first.state)
+    assert int(meta["step"]) == 5
+    _assert_bitwise(saved, legacy[4][0])
+    tr = make(9)
+    assert tr.restore() == 5
+    state, history = tr.run()
+    _assert_bitwise(state, legacy[8][0])
+    assert history[-1]["step"] == 8
+
+
 def test_trainer_counts_encoder_tokens():
     """tok/s accounting: MT batches consume enc_tokens + tokens; LM only
     tokens (the seed launcher counted decoder tokens only — ~2x under
