@@ -60,18 +60,8 @@ def bench_table8(fast):
     return main(fast)
 
 
-def bench_table9(fast):
-    from benchmarks.table9_comm import main
-    return main(fast)
-
-
 def bench_table10(fast):
     from benchmarks.table10_paged import main
-    return main(fast)
-
-
-def bench_table11(fast):
-    from benchmarks.table11_overlap import main
     return main(fast)
 
 
@@ -122,9 +112,7 @@ BENCHES = {
     "table6": bench_table6,
     "table7": bench_table7,
     "table8": bench_table8,
-    "table9": bench_table9,
     "table10": bench_table10,
-    "table11": bench_table11,
     "table12": bench_table12,
     "roofline": bench_roofline,
     "kernels": bench_kernels,

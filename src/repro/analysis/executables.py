@@ -1,8 +1,8 @@
 """Named-executable registry for the lint suite (DESIGN.md §12).
 
-Mirrors the PR-1 backend / PR-5 substrate registries: every program the
+Mirrors the backend registry (core/backend.py): every program the
 system ships — the fused train chunk (routed + host_cond dropped), the
-sharded MoE layer under all four substrates, decode_pool_step with and
+sharded MoE layer (routed + Gate-Drop local), decode_pool_step with and
 without ``local_routing``, the pallas_fused forward/VJP, the unfused
 pallas pipeline, the flash-decode step — registers here as an
 ExecutableSpec that can lower itself under the small CPU device mesh,
@@ -118,39 +118,30 @@ def get_executable(name: str) -> ExecutableSpec:
 # shared config builders (host math only)
 # --------------------------------------------------------------------------
 
-def _moe_cfg(substrate: str = "dense", *, backend: str = "sharded",
-             dtype: str = "float32", top_k: int = 2, gated: bool = True,
-             d_model: int = 32, d_ff: int = 64, n_experts: int = 8,
-             n_chunks: int = 4):
-    from repro.configs.base import (CommConfig, GatingDropoutConfig,
-                                    ModelConfig, MoEConfig)
+def _moe_cfg(*, backend: str = "sharded", dtype: str = "float32",
+             top_k: int = 2, gated: bool = True, d_model: int = 32,
+             d_ff: int = 64, n_experts: int = 8):
+    from repro.configs.base import (GatingDropoutConfig, ModelConfig,
+                                    MoEConfig)
     return ModelConfig(
         d_model=d_model, d_ff=d_ff, vocab=64, dtype=dtype,
         gated_mlp=gated,
         moe=MoEConfig(n_experts=n_experts, top_k=top_k, d_ff_expert=d_ff,
-                      jitter_eps=0.0,
-                      comm=CommConfig(substrate=substrate,
-                                      n_chunks=n_chunks),
-                      backend=backend,
+                      jitter_eps=0.0, backend=backend,
                       gating_dropout=GatingDropoutConfig(
                           mode="gate_drop", rate=0.3)))
 
 
-def _train_cfg(substrate: str = "hierarchical_compressed", *,
-               n_chunks: int = 4):
-    from repro.configs.base import (CommConfig, GatingDropoutConfig,
-                                    ModelConfig, MoEConfig)
+def _train_cfg():
+    from repro.configs.base import (GatingDropoutConfig, ModelConfig,
+                                    MoEConfig)
     # scan_layers=False: HLO counts a scanned segment body ONCE; the cost
     # model prices per MoE layer — unrolled, the two agree exactly.
-    # (Overlapped substrates are already HLO-exact under scan: the chunk
-    # pipeline is an unrolled Python loop, DESIGN.md §14.)
     return ModelConfig(
         d_model=32, d_ff=64, vocab=64, n_layers=2, n_heads=2, n_kv_heads=2,
         remat=False, scan_layers=False, dtype="float32",
         param_dtype="float32",
         moe=MoEConfig(n_experts=8, top_k=1, d_ff_expert=64, jitter_eps=0.0,
-                      comm=CommConfig(substrate=substrate,
-                                      n_chunks=n_chunks),
                       backend="sharded",
                       gating_dropout=GatingDropoutConfig(
                           mode="gate_drop", rate=0.3,
@@ -178,7 +169,7 @@ def _layer_cost_expect(cfg, *, tokens_per_shard: int, ep: int):
 
 
 def _step_cost_expect(cfg, *, tokens_per_shard: int, ep: int):
-    from repro.comm.cost import step_cost
+    from repro.comm import step_cost
     c = step_cost(cfg, tokens_per_shard=tokens_per_shard, ep=ep,
                   backward=True)
     return {"cost": {"calls": c["calls"], "bytes": c["bytes"],
@@ -189,12 +180,12 @@ def _step_cost_expect(cfg, *, tokens_per_shard: int, ep: int):
 # builders (device-touching, lazy)
 # --------------------------------------------------------------------------
 
-def _build_moe_layer(substrate: str, decision: bool):
+def _build_moe_layer(decision: bool):
     def build():
         import jax
         from repro.core import init_moe_params, moe_sharded, ParallelContext
         from repro.launch.mesh import make_mesh
-        cfg = _moe_cfg(substrate)
+        cfg = _moe_cfg()
         ctx = ParallelContext(mesh=make_mesh((8,), ("data",)))
         p = init_moe_params(jax.random.PRNGKey(0), cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, 32))
@@ -206,9 +197,7 @@ def _build_moe_layer(substrate: str, decision: bool):
     return build
 
 
-def _build_train_chunk(decision: bool,
-                       substrate: str = "hierarchical_compressed",
-                       frame: bool = True):
+def _build_train_chunk(decision: bool, frame: bool = True):
     def build():
         import jax
         import jax.numpy as jnp
@@ -218,7 +207,7 @@ def _build_train_chunk(decision: bool,
         from repro.models import init_model
         from repro.training.loop import make_chunk_step
         from repro.training.steps import init_train_state
-        cfg = _train_cfg(substrate)
+        cfg = _train_cfg()
         tc = TrainConfig(lr=1e-3, warmup_steps=4, seed=0,
                          metrics_frame=frame)
         ctx = ParallelContext(mesh=make_mesh((8,), ("data",)))
@@ -521,23 +510,16 @@ def _paged_scheduler_scenario():
 _VMEM = {"budget_bytes": 16 << 20}
 _DTYPE = {"min_elems": 4096}
 
-# all eight substrates (DESIGN.md §10, §14): the overlapped rows assert
-# the §14 invariant in the lint gate — a2a call count = n_eff x the base
-# substrate's at EXACTLY equal total bytes/wire (the chunk pipeline is
-# an unrolled loop, so HLO carries each per-chunk collective distinctly)
-from repro.configs.base import COMM_SUBSTRATES as _ALL_SUBS  # noqa: E402
-
-for _sub in _ALL_SUBS:
-    register_executable(ExecutableSpec(
-        name=f"moe_layer/{_sub}",
-        build=_build_moe_layer(_sub, decision=False),
-        expect={"no-collectives": _layer_cost_expect(
-            _moe_cfg(_sub), tokens_per_shard=16, ep=8)},
-        n_devices=8))
+register_executable(ExecutableSpec(
+    name="moe_layer/routed",
+    build=_build_moe_layer(decision=False),
+    expect={"no-collectives": _layer_cost_expect(
+        _moe_cfg(), tokens_per_shard=16, ep=8)},
+    n_devices=8))
 
 register_executable(ExecutableSpec(
     name="moe_layer/local",
-    build=_build_moe_layer("dense", decision=True),
+    build=_build_moe_layer(decision=True),
     expect={"no-collectives": {"zero": True}},
     n_devices=8))
 
@@ -563,19 +545,6 @@ register_executable(ExecutableSpec(
     build=_build_train_chunk(decision=False, frame=False),
     expect={"no-collectives": _step_cost_expect(
         _train_cfg(), tokens_per_shard=16, ep=8)},
-    n_devices=8))
-
-register_executable(ExecutableSpec(
-    name="train_chunk/overlapped",
-    build=_build_train_chunk(decision=False, substrate="overlapped"),
-    expect={"no-collectives": _step_cost_expect(
-        _train_cfg("overlapped"), tokens_per_shard=16, ep=8)},
-    n_devices=8))
-
-register_executable(ExecutableSpec(
-    name="train_chunk/overlapped_dropped",
-    build=_build_train_chunk(decision=True, substrate="overlapped"),
-    expect={"no-collectives": {"zero": True}},
     n_devices=8))
 
 register_executable(ExecutableSpec(

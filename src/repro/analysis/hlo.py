@@ -10,8 +10,7 @@ kept as a function here so every caller migrated without changing
 its numbers) and fixes its two latent bugs:
 
   * unknown payload dtypes silently priced at 4 bytes — ``shape_bytes``
-    now raises, and the table covers the int8/fp8/pred wire dtypes the
-    compressed substrate actually moves;
+    now raises, and the table covers the int8/fp8/pred wire dtypes;
   * ``get-tuple-element`` lines were excluded only because layout braces
     ``{2,1,0}`` happened to break the old shape regex — the walker
     matches opcodes structurally, so textual noise like an operand named
@@ -29,8 +28,8 @@ __all__ = ["DTYPE_BYTES", "HloComputation", "HloInstr", "HloModule",
            "HloShape", "UnknownDtypeError", "collectives_summary",
            "parse_collectives", "parse_hlo", "shape_bytes"]
 
-# wire width of every dtype XLA prints in shapes. THE dtype table of the
-# repo: comm/cost.py prices quantized substrate payloads off it too.
+# wire width of every dtype XLA prints in shapes: THE dtype table of the
+# repo.
 DTYPE_BYTES: Dict[str, int] = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
     "f8e4m3fn": 1, "f8e5m2": 1, "f8e4m3b11fnuz": 1, "f8e4m3fnuz": 1,
@@ -281,7 +280,7 @@ def _wire_bytes(op: str, payload: float, g: int) -> float:
 
 def collectives_summary(module: HloModule) -> Dict[str, Dict[str, float]]:
     """Per-kind collective counts/bytes over a parsed module — the
-    numbers comm/cost.py and the substrate telemetry are pinned to.
+    numbers comm/exchange.py's cost model and telemetry are pinned to.
     ``bytes`` sums per-device RESULT bytes (tuple results summed),
     ``wire_bytes`` applies the ring model per op."""
     out: Dict[str, Dict[str, float]] = {}

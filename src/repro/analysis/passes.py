@@ -1,6 +1,6 @@
 """Lint pass registry (DESIGN.md §12).
 
-Mirrors the PR-1 backend / PR-5 substrate registries: passes register
+Mirrors the backend registry (core/backend.py): passes register
 under an id via ``@register_pass`` and run against every executable in
 the registry (``analysis/executables.py``) whose spec opts in by
 carrying an expectation for that pass. A pass returns Findings — never
@@ -99,8 +99,9 @@ def no_collectives_pass(spec, art) -> List[Finding]:
     """Zero-communication / bytes-equality gate: Gate-Drop LOCAL,
     dropped-chunk, and local-routing executables must compile to ZERO
     all-to-alls (the paper's §3 structural claim); routed executables'
-    all-to-all count/bytes must equal the comm/cost.py analytic model
-    (the PR-5 telemetry==HLO contract, through the IR walker)."""
+    all-to-all count/bytes must equal the comm/exchange.py bytes model
+    (the telemetry == cost == HLO contract, DESIGN.md §10, through the
+    IR walker)."""
     from repro.analysis.hlo import collectives_summary
     exp = spec.expect.get("no-collectives")
     if exp is None:

@@ -1,12 +1,7 @@
-"""Pluggable collective-communication substrate for expert dispatch
-(DESIGN.md §10): substrate registry + transports (`substrate.py`) and the
-HLO-validated analytic bytes model (`cost.py`)."""
-from repro.comm import cost  # noqa: F401  (must precede substrate)
-from repro.comm.cost import (effective_chunks, ep_tier_groups,  # noqa: F401
-                             factored_ep, format_table, layer_cost,
-                             pipeline_time, step_cost, substrate_table,
-                             transport_cost, transport_time)
-from repro.comm.substrate import (CommConfig, CommEnv, Transport,  # noqa: F401
-                                  available_substrates, comm_zero,
-                                  dequantize, get_substrate, make_transport,
-                                  quantize, register_substrate)
+"""The expert exchange (DESIGN.md §10): the all-to-all pair of a routed
+MoE layer, its virtual emulation, its telemetry and its bytes model
+(`exchange.py`)."""
+from repro.comm.exchange import (Exchange, combine, comm_zero,  # noqa: F401
+                                 dispatch, exchange_cost, layer_cost,
+                                 routed_capacity, step_cost, vcombine,
+                                 vdispatch)

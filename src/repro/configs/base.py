@@ -49,106 +49,6 @@ class GatingDropoutConfig:
 
 
 # ---------------------------------------------------------------------------
-# Communication substrate (DESIGN.md §10, §14)
-# ---------------------------------------------------------------------------
-
-@dataclass(frozen=True)
-class Topology:
-    """Two-tier interconnect descriptor (DESIGN.md §14): maps the
-    hierarchical substrate's ep_inner/ep_outer tiers onto link classes so
-    the cost model can price a simulated two-tier mesh. ``intra_gbps`` is
-    the intra-tier (ICI / NVLink-class) per-device bandwidth in GB/s;
-    ``inter_gbps`` the inter-tier (DCN / IB-class) bandwidth. Flat
-    substrates span every tier, so ALL their wire is priced at
-    ``inter_gbps`` — the pessimistic cross-machine rate."""
-    intra_gbps: float = 400.0
-    inter_gbps: float = 50.0
-
-    def __post_init__(self):
-        assert self.intra_gbps > 0 and self.inter_gbps > 0
-
-    @property
-    def intra_bps(self) -> float:
-        return self.intra_gbps * 1e9
-
-    @property
-    def inter_bps(self) -> float:
-        return self.inter_gbps * 1e9
-
-
-COMM_SUBSTRATES = (
-    "dense", "hierarchical", "compressed", "hierarchical_compressed",
-    "overlapped", "overlapped_hierarchical", "overlapped_compressed",
-    "overlapped_hierarchical_compressed")
-
-
-@dataclass(frozen=True)
-class CommConfig:
-    """Collective-communication substrate for the MoE dispatch/combine path
-    (comm/substrate.py registry, DESIGN.md §10, §14).
-
-    substrate:
-      "dense"                   -- single-hop all-to-all over the full ep
-                                   group (the historical inline path).
-      "hierarchical"            -- two-hop all-to-all over a factored
-                                   ep = ep_inner x ep_outer group:
-                                   intra-tier exchange first, then
-                                   inter-tier — same permutation as dense
-                                   (bitwise), 1/ep_inner the inter-tier
-                                   message count.
-      "compressed"              -- dense topology, payload quantized to
-                                   ``quant`` with one f32 scale per
-                                   (expert, slot) row; dequant on arrival;
-                                   custom VJP (straight-through + the
-                                   reverse wire also compressed) so the
-                                   routed path still trains.
-      "hierarchical_compressed" -- both.
-      "overlapped[...]"         -- any of the above, micro-chunked along
-                                   the capacity axis into ``n_chunks``
-                                   pieces whose dispatch/combine
-                                   collectives pipeline behind the expert
-                                   FFN of the previous chunk (DESIGN.md
-                                   §14). Same permutation per chunk, so
-                                   bitwise-equal to its base substrate;
-                                   the wire bytes are identical, only the
-                                   EXPOSED (non-overlappable) fraction
-                                   shrinks to 1/n_chunks.
-    quant: wire dtype for compressed substrates: "int8" | "fp8"
-      (float8_e4m3fn).
-    ep_inner: intra-tier group size for hierarchical substrates (must
-      divide ep); 0 = auto (largest divisor <= sqrt(ep)).
-    n_chunks: requested micro-chunk count for overlapped substrates
-      (actual count = largest divisor of the capacity <= n_chunks, see
-      comm/cost.py::effective_chunks); ignored by non-overlapped ones.
-    topology: two-tier bandwidth descriptor the cost model prices the
-      wire with (pure-math time estimates only; never changes numerics).
-    """
-    substrate: str = "dense"
-    quant: str = "int8"
-    ep_inner: int = 0
-    n_chunks: int = 4
-    topology: Topology = field(default_factory=Topology)
-
-    def __post_init__(self):
-        assert self.substrate in COMM_SUBSTRATES, self.substrate
-        assert self.quant in ("int8", "fp8"), self.quant
-        assert self.ep_inner >= 0
-        assert self.n_chunks >= 1, self.n_chunks
-
-    @property
-    def overlapped(self) -> bool:
-        return self.substrate.startswith("overlapped")
-
-    @property
-    def hierarchical(self) -> bool:
-        return "hierarchical" in self.substrate
-
-    @property
-    def compressed(self) -> bool:
-        return self.substrate.endswith("compressed")
-
-
-# ---------------------------------------------------------------------------
 # MoE
 # ---------------------------------------------------------------------------
 
@@ -172,8 +72,6 @@ class MoEConfig:
     # Execution backend (core/backend.py registry, DESIGN.md §6):
     #   auto | oracle | sharded | pallas | pallas_fused (megakernel, §11)
     backend: str = "auto"
-    # Collective-communication substrate for dispatch/combine (DESIGN.md §10)
-    comm: CommConfig = field(default_factory=CommConfig)
     gating_dropout: GatingDropoutConfig = field(default_factory=GatingDropoutConfig)
 
     def __post_init__(self):

@@ -1,18 +1,24 @@
 """MoE execution-backend registry (DESIGN.md §6).
 
-One MoE layer, three interchangeable execution paths, selected by
+One MoE layer, four registered execution paths, selected by
 ``MoEConfig.backend``:
 
-  oracle  -- pure-jnp vmap over virtual shards; ground truth. Runs anywhere.
-  sharded -- shard_map over a real mesh; dispatch/combine are explicit
-             ``jax.lax.all_to_all`` collectives (the path Gating Dropout
-             skips on dropped steps).
-  pallas  -- the compiled kernel pipeline: routing tables built ONCE per
-             step (kernels.ops.routing_tables), then scalar-prefetch
-             dispatch gather -> grouped-matmul expert FFN -> weighted
-             combine gather. interpret mode auto-detected per platform.
-  auto    -- (default) sharded when a real mesh is active, oracle otherwise
-             — the historical moe_apply behavior.
+  oracle       -- pure-jnp vmap over virtual shards; ground truth. Runs
+                  anywhere.
+  sharded      -- shard_map over a real mesh; dispatch/combine are the
+                  expert exchange's ``jax.lax.all_to_all`` collectives
+                  (the path Gating Dropout skips on dropped steps).
+  pallas       -- the compiled kernel pipeline: routing tables built ONCE
+                  per step (kernels.ops.routing_tables), then
+                  scalar-prefetch dispatch gather -> grouped-matmul expert
+                  FFN -> weighted combine gather. interpret mode
+                  auto-detected per platform. Under a real mesh it runs
+                  ``sharded`` with the per-shard kernels.
+  pallas_fused -- the one-launch megakernel (DESIGN.md §11); ep=1 only.
+
+``auto`` (the default) is not a path of its own: ``resolve_backend``
+picks sharded when a real mesh is active, oracle otherwise — the
+historical moe_apply behavior.
 
 New fast paths register here (``@register_backend("name")``) and become
 selectable via config + one parity test, instead of forking moe.py. All
@@ -118,8 +124,6 @@ def pallas_backend(params: Params, x: jax.Array, cfg: ModelConfig, ctx=None,
                                    token_ids=token_ids,
                                    token_valid=token_valid)
 
-    from repro.comm import CommEnv, make_transport
-
     moe = cfg.moe
     shape = x.shape
     xf = x.reshape(-1, shape[-1])
@@ -132,21 +136,15 @@ def pallas_backend(params: Params, x: jax.Array, cfg: ModelConfig, ctx=None,
     experts = params["experts"]
     cf = moe.capacity_factor if is_training else moe.eval_capacity_factor
     cap = min(R.capacity(T, E, moe.top_k, cf), T)
-    # ep=1 wire: no movement, but the payload transform (compressed
-    # substrates' quant->dequant) still applies so backend choice never
-    # changes numerics vs the oracle (DESIGN.md §10)
-    transport = make_transport(moe.comm, CommEnv(ep=1))
 
     def _pipeline(info: R.DispatchInfo) -> jax.Array:
         tables = K.routing_tables(info, E, cap)    # built once, reused twice
         buf = K.dispatch(xf, tables.slot_token, tables.slot_valid,
                          interpret=interpret).reshape(E, cap, -1)
-        buf = transport.roundtrip(buf)
         w_in = experts["w_in"]
         out = K.expert_ffn_op(buf.astype(w_in.dtype), w_in,
                               experts.get("w_gate"), experts["w_out"],
-                              cfg.act, interpret=interpret)
-        out = transport.roundtrip(out.astype(xf.dtype))
+                              cfg.act, interpret=interpret).astype(xf.dtype)
         return K.combine(out.reshape(E * cap, -1), tables.token_slot,
                          info.topk_w, info.keep, interpret=interpret)
 
@@ -154,9 +152,8 @@ def pallas_backend(params: Params, x: jax.Array, cfg: ModelConfig, ctx=None,
         rr = R.route(wr, xf, moe, rng=_shard_rng(rng, 0),
                      is_training=is_training, token_ids=tok)
         info = R.dispatch_info(rr, E, cap, valid=tv)
-        comm_t = transport.telemetry(E, cap, shape[-1],
-                                     jnp.dtype(xf.dtype).itemsize)
-        return _pipeline(info), _routed_aux(rr, info, moe, comm=comm_t)
+        # ep=1: the exchange moves nothing, its telemetry is zero
+        return _pipeline(info), _routed_aux(rr, info, moe)
 
     def local():
         # ep=1 Gate-Drop: the "local group" is all E experts (mirrors
@@ -191,22 +188,19 @@ def pallas_fused_backend(params: Params, x: jax.Array, cfg: ModelConfig,
     (E, C, d) buffer and its two extra HBM roundtrips are gone, and the
     five per-layer kernel launches collapse to one.
 
-    Raises under a real mesh (expert parallelism needs the materialized
-    buffer on the wire) or a compressed substrate (the quant->dequant
-    payload transform applies to that buffer) rather than turn into the
-    `pallas` path: choose `pallas` there. ep=1 dense/hierarchical wires are
-    identity, so skipping them changes nothing (DESIGN.md §10)."""
+    Raises under a real mesh (the expert exchange needs the materialized
+    buffer on the wire) rather than turn into the `pallas` path: choose
+    `pallas` there. At ep=1 the exchange moves nothing, so skipping it
+    changes nothing (DESIGN.md §10)."""
     from repro.core.moe import (_local_adjust, _local_aux, _routed_aux,
                                 _select_branch, _shard_rng, _zero_aux)
     from repro.kernels import ops as K
 
     moe = cfg.moe
-    if (ctx is not None and ctx.active) or moe.comm.compressed:
+    if ctx is not None and ctx.active:
         raise ValueError(
-            "backend 'pallas_fused' has no expert-parallel or compressed-"
-            "wire path; use backend 'pallas'")
-
-    from repro.comm import CommEnv, make_transport
+            "backend 'pallas_fused' has no expert-parallel path; use "
+            "backend 'pallas'")
 
     shape = x.shape
     xf = x.reshape(-1, shape[-1])
@@ -219,9 +213,6 @@ def pallas_fused_backend(params: Params, x: jax.Array, cfg: ModelConfig,
     experts = params["experts"]
     cf = moe.capacity_factor if is_training else moe.eval_capacity_factor
     cap = min(R.capacity(T, E, moe.top_k, cf), T)
-    # telemetry priced identically to `pallas` (ep=1 wire) so the aux dict
-    # is backend-invariant; the identity roundtrip itself is fused away
-    transport = make_transport(moe.comm, CommEnv(ep=1))
 
     def _pipeline(info: R.DispatchInfo) -> jax.Array:
         tables = K.routing_tables(info, E, cap)
@@ -234,9 +225,8 @@ def pallas_fused_backend(params: Params, x: jax.Array, cfg: ModelConfig,
         rr = R.route(wr, xf, moe, rng=_shard_rng(rng, 0),
                      is_training=is_training, token_ids=tok)
         info = R.dispatch_info(rr, E, cap, valid=tv)
-        comm_t = transport.telemetry(E, cap, shape[-1],
-                                     jnp.dtype(xf.dtype).itemsize)
-        return _pipeline(info), _routed_aux(rr, info, moe, comm=comm_t)
+        # ep=1: the exchange moves nothing, its telemetry is zero
+        return _pipeline(info), _routed_aux(rr, info, moe)
 
     def local():
         rr = R.route(wr, xf, moe, rng=_shard_rng(rng, 0),

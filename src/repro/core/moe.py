@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.comm import CommEnv, comm_zero, make_transport
+from repro.comm import Exchange, comm_zero, vcombine, vdispatch
 from repro.configs.base import ModelConfig, MoEConfig
 from repro.core import router as R
 
@@ -168,8 +168,8 @@ def _shard_rng(rng, my_shard):
 def _routed_aux(rr, info, moe: MoEConfig, comm=None) -> Dict[str, jax.Array]:
     """Aux dict for a routed step — shared by every backend so metric
     semantics cannot desync (DESIGN.md §6). ``comm`` carries the layer's
-    in-graph transport telemetry (DESIGN.md §10); None = no wire (ep=1
-    kernel pipeline before the substrate is consulted)."""
+    in-graph exchange telemetry (DESIGN.md §10); None = no wire (the
+    ep=1 kernel pipelines)."""
     return {
         "balance": R.balance_loss(rr, moe) if moe.router_type != "hash"
                    else jnp.zeros(()),
@@ -217,15 +217,22 @@ def _token_valid_tk(token_valid, k: int):
                             (token_valid.size, k))
 
 
+def make_transport(moe: MoEConfig, exchange: Exchange) -> Exchange:
+    """The wire of one routed layer of ``moe_sharded``: ``exchange``
+    itself. ``moe_sharded`` looks this name up when it traces, so a test
+    can put a wire that leaves the exchange out in its place
+    (``bench/tests/test_bench_faults.py``)."""
+    return exchange
+
+
 def _routed_shard(wr, experts, xf, moe: MoEConfig, cfg: ModelConfig, rng,
                   is_training, token_ids, my_shard, ep: int, tp_axis,
                   transport, token_valid=None):
     """Normal MoE step on one shard: route -> dispatch -> (wire) -> FFN ->
-    (wire) -> combine. The wire is the configured comm substrate
-    (``MoEConfig.comm``, DESIGN.md §10); ``dense`` is bit-for-bit the
-    historical inline all-to-all pair. ``token_valid`` masks tokens
-    (retired serving slots) out of capacity competition — they neither
-    dispatch nor combine."""
+    (wire) -> combine. The wire is the expert exchange (DESIGN.md §10),
+    bit-for-bit the historical inline all-to-all pair. ``token_valid``
+    masks tokens (retired serving slots) out of capacity competition —
+    they neither dispatch nor combine."""
     T = xf.shape[0]
     E = moe.n_experts
     cf = moe.capacity_factor if is_training else moe.eval_capacity_factor
@@ -244,10 +251,8 @@ def _routed_shard(wr, experts, xf, moe: MoEConfig, cfg: ModelConfig, rng,
         buf = R.dispatch(xf, info, E, cap)                   # (E, cap, d)
     comm_t = transport.telemetry(E, cap, xf.shape[-1],
                                  jnp.dtype(buf.dtype).itemsize)
-    # dispatch wire -> grouped FFN -> combine wire, as ONE transport
-    # transaction (DESIGN.md §14): (E, cap, d) -> (E/ep, ep*cap, d) ->
-    # FFN -> (E, cap, d). Overlapped substrates chunk the capacity axis
-    # and pipeline the per-chunk collectives behind the FFN body.
+    # dispatch wire -> grouped FFN -> combine wire:
+    # (E, cap, d) -> (E/ep, ep*cap, d) -> FFN -> (E, cap, d)
     out = transport.pipelined(
         buf, lambda b: _expert_ffn(experts, b, cfg, tp_axis))
     y = (K.moe_combine_op(out, info, tables=tables) if K.KERNELS_ENABLED
@@ -333,7 +338,6 @@ def moe_oracle(params: Params, x: jax.Array, cfg: ModelConfig, *,
         Tl = T // ep
         cf = moe.capacity_factor if is_training else moe.eval_capacity_factor
         cap = min(R.capacity(Tl, E, moe.top_k, cf), Tl)
-        transport = make_transport(moe.comm, CommEnv(ep=ep))
 
         def shard_dispatch(my, xl, tl, tvl):
             rr = R.route(wr, xl, moe, rng=_shard_rng(rng, my),
@@ -346,10 +350,10 @@ def moe_oracle(params: Params, x: jax.Array, cfg: ModelConfig, *,
             shard_dispatch, in_axes=(0, 0, 0 if tok is not None else None,
                                      0 if tv is not None else None))(
             jnp.arange(ep), xs, tok, tv)
-        # virtual wire (substrate emulation), one pipelined transaction:
-        # (ep, E, cap, d) -> (E, ep*cap, d) -> FFN -> (ep, E, cap, d)
-        outs = transport.vpipelined(
-            bufs, lambda b: _expert_ffn(experts, b, cfg, None))
+        # virtual wire: (ep, E, cap, d) -> (E, ep*cap, d) -> FFN ->
+        # (ep, E, cap, d)
+        outs = vcombine(_expert_ffn(experts, vdispatch(bufs), cfg, None),
+                        ep)
         y = jax.vmap(R.combine)(outs, infos)
         aux = {
             "balance": jax.vmap(lambda r: R.balance_loss(r, moe))(rrs).mean()
@@ -359,8 +363,8 @@ def moe_oracle(params: Params, x: jax.Array, cfg: ModelConfig, *,
             "load": jax.vmap(lambda r: R.expert_load(r, moe))(rrs).mean(0),
             "router_entropy": jax.vmap(R.route_entropy)(rrs).mean(),
             "dropped_frac": 1.0 - infos.keep.mean(),
-            **transport.telemetry(E, cap, shape[-1],
-                                  jnp.dtype(x.dtype).itemsize),
+            **Exchange(ep).telemetry(E, cap, shape[-1],
+                                     jnp.dtype(x.dtype).itemsize),
         }
         return y.reshape(ep * (T // ep), -1), aux
 
@@ -433,21 +437,18 @@ def moe_sharded(params: Params, x: jax.Array, cfg: ModelConfig,
     if ep_on_model:
         ep = ctx.ep * ctx.tp
         tp_axis = None
-        # the ep group IS the (data x model) axis pair: hierarchical
-        # substrates use those axes as the two tiers (model = intra)
-        env = CommEnv(ep=ep, axis=(ctx.ep_axis, ctx.tp_axis),
-                      inner_axis=ctx.tp_axis, outer_axis=ctx.ep_axis,
-                      inner_size=ctx.tp)
+        # the ep group IS the (data x model) axis pair
+        exchange = Exchange(ep, (ctx.ep_axis, ctx.tp_axis))
         x_spec = P(dp, ctx.tp_axis, None)
         tok_spec = P(dp, ctx.tp_axis)
     else:
         ep = ctx.ep
         tp_axis = ctx.tp_axis if ctx.tp > 1 else None
-        env = CommEnv(ep=ep, axis=ctx.ep_axis)
+        exchange = Exchange(ep, ctx.ep_axis)
         x_spec = P(dp, None, None)
         tok_spec = P(dp, None)
     assert E % ep == 0, (E, ep)
-    transport = make_transport(moe.comm, env)
+    transport = make_transport(moe, exchange)
 
     # Python-bool / None decisions are baked into the executable (host_cond):
     # the dropped executable contains no all-to-all. Traced decisions are

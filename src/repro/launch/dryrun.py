@@ -223,36 +223,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
     return res
 
 
-def comm_table(arch: str, shape_name: str, *, multi_pod: bool = False,
-               quant: str = "int8", n_chunks: int = 0) -> Dict[str, Any]:
-    """Per-substrate predicted wire bytes for (arch x shape) on the
-    production mesh — the DESIGN.md §10/§14 what-if table with exposed
-    bytes and bandwidth-weighted two-tier time estimates. Pure cost-model
-    math (comm/cost.py): nothing is lowered, compiled, or run."""
-    from repro.comm import format_table, substrate_table
-    cfg = get_config(arch)
-    assert cfg.moe is not None, f"{arch} has no MoE layer to dispatch"
-    shape = INPUT_SHAPES[shape_name]
-    axes = ({"pod": 2, "data": 16, "model": 16} if multi_pod
-            else {"data": 16, "model": 16})
-    dp = axes["data"] * axes.get("pod", 1)     # batch-sharding axes (§4)
-    ep = axes["data"]                          # EP group == data axis
-    tokens = (shape.global_batch if shape.kind == "decode"
-              else shape.global_batch * shape.seq_len)
-    per_shard = max(tokens // dp, 1)
-    table = substrate_table(cfg, tokens_per_shard=per_shard, ep=ep,
-                            is_training=shape.kind == "train",
-                            quant=quant, n_chunks=n_chunks)
-    mesh_name = "pod512" if multi_pod else "pod256"
-    nc = n_chunks or cfg.moe.comm.n_chunks
-    print(f"[comm-table] {arch} x {shape_name} x {mesh_name}: "
-          f"{per_shard} tokens/device, ep={ep}, quant={quant}, "
-          f"n_chunks={nc} "
-          f"(per-device FORWARD bytes per step; train backward doubles)")
-    print(format_table(table))
-    return table
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
@@ -260,16 +230,6 @@ def main():
     ap.add_argument("--all", action="store_true",
                     help="run every applicable (arch x shape)")
     ap.add_argument("--multi-pod", action="store_true")
-    ap.add_argument("--comm-table", action="store_true",
-                    help="print the per-substrate predicted bytes table "
-                         "for --arch x --shape (comm/cost.py; no "
-                         "compile, no step)")
-    ap.add_argument("--comm-quant", default="int8", choices=["int8", "fp8"],
-                    help="wire dtype the --comm-table prices compressed "
-                         "substrates at")
-    ap.add_argument("--comm-chunks", type=int, default=0,
-                    help="capacity micro-chunks the --comm-table prices "
-                         "overlapped substrates at (0 = config default)")
     ap.add_argument("--lint-table", action="store_true",
                     help="print the static lint pass x executable matrix "
                          "(analysis/lint.py; pure lowering, nothing is "
@@ -292,11 +252,6 @@ def main():
     args = ap.parse_args()
     set_tracer(Tracer(enabled=bool(args.trace_out)))
     reg = MetricsRegistry()
-    if args.comm_table:
-        assert args.arch and args.shape, "--comm-table needs --arch --shape"
-        comm_table(args.arch, args.shape, multi_pod=args.multi_pod,
-                   quant=args.comm_quant, n_chunks=args.comm_chunks)
-        return
     if args.lint_table:
         from repro.analysis.lint import format_lint_table, lint_table
         print(format_lint_table(lint_table()))

@@ -99,8 +99,8 @@ def _pcts(xs):
 
 
 def trace_comm_section(cfg, gen, sched, ep: int) -> dict:
-    """Price every executed device call of a trace with the substrate
-    bytes model (comm/cost.py, DESIGN.md §10): per-tick wire bytes a
+    """Price every executed device call of a trace with the exchange's
+    bytes model (comm/exchange.py, DESIGN.md §10): per-tick wire bytes a
     sharded deployment at expert-parallel width ``ep`` would move, as
     totals + percentiles. Decode ticks under ``--local-routing`` move
     zero bytes (the Gate-Drop local path has no all-to-all)."""
@@ -108,26 +108,17 @@ def trace_comm_section(cfg, gen, sched, ep: int) -> dict:
     from repro.training.steps import n_moe_layers
     nl = n_moe_layers(cfg)
     per_tick = []
-    exposed_tick = []
     for kind, toks in sched.tick_log:
         if kind == "decode" and gen.local_routing:
             per_tick.append(0.0)
-            exposed_tick.append(0.0)
             continue
         c = layer_cost(cfg, tokens_per_shard=max(toks // ep, 1), ep=ep,
                        is_training=False)
         per_tick.append(c["wire_bytes"] * nl)
-        exposed_tick.append(c["exposed_wire_bytes"] * nl)
     return {
-        "substrate": cfg.moe.comm.substrate,
-        "quant": cfg.moe.comm.quant,
-        "n_chunks": cfg.moe.comm.n_chunks,
         "ep_model": ep,
         "n_ticks": len(per_tick),
         "wire_bytes_total": float(sum(per_tick)),
-        # §14 split: wire an overlapped substrate cannot hide behind the
-        # expert FFN of the same tick (= total for non-overlapped)
-        "exposed_bytes_total": float(sum(exposed_tick)),
         "wire_bytes_per_tick": _pcts(per_tick) if per_tick else {},
     }
 
@@ -246,17 +237,6 @@ def main():
                     choices=[None, "auto", "oracle", "sharded", "pallas",
                              "pallas_fused"],
                     help="MoE execution backend (DESIGN.md §6, §11)")
-    from repro.configs.base import COMM_SUBSTRATES
-    ap.add_argument("--comm", default=None,
-                    choices=[None, *COMM_SUBSTRATES],
-                    help="communication substrate for expert dispatch "
-                         "(DESIGN.md §10, §14)")
-    ap.add_argument("--comm-quant", default=None,
-                    choices=[None, "int8", "fp8"],
-                    help="wire dtype for compressed substrates")
-    ap.add_argument("--comm-chunks", type=int, default=None,
-                    help="overlapped substrates: capacity micro-chunks "
-                         "pipelined behind expert compute")
     ap.add_argument("--comm-ep", type=int, default=1,
                     help="expert-parallel width the --trace comm "
                          "accounting prices the wire at (default 1 = "
@@ -310,17 +290,9 @@ def main():
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
-    if cfg.moe is not None and (args.backend or args.comm
-                                or args.comm_quant
-                                or args.comm_chunks is not None):
-        comm = dataclasses.replace(
-            cfg.moe.comm,
-            substrate=args.comm or cfg.moe.comm.substrate,
-            quant=args.comm_quant or cfg.moe.comm.quant,
-            n_chunks=args.comm_chunks if args.comm_chunks is not None
-            else cfg.moe.comm.n_chunks)
+    if cfg.moe is not None and args.backend:
         cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
-            cfg.moe, backend=args.backend or cfg.moe.backend, comm=comm))
+            cfg.moe, backend=args.backend))
     # distinct PRNG streams: params / prompts / sampling
     key = jax.random.PRNGKey(args.seed)
     key_params = jax.random.fold_in(key, 0)
@@ -347,7 +319,7 @@ def main():
         if "comm" in rec:
             c = rec["comm"]
             pt = c["wire_bytes_per_tick"]
-            print(f"comm[{c['substrate']}@ep={c['ep_model']}]: "
+            print(f"comm[ep={c['ep_model']}]: "
                   f"{c['wire_bytes_total']/2**20:.2f} MiB wire over "
                   f"{c['n_ticks']} ticks; per-tick KiB p50/p90/p99: "
                   + "/".join(f"{pt[p]/2**10:.1f}" for p in (50, 90, 99)))

@@ -102,20 +102,6 @@ def main():
                     choices=[None, "auto", "oracle", "sharded", "pallas",
                              "pallas_fused"],
                     help="MoE execution backend (DESIGN.md §6, §11)")
-    from repro.configs.base import COMM_SUBSTRATES
-    ap.add_argument("--comm", default=None,
-                    choices=[None, *COMM_SUBSTRATES],
-                    help="communication substrate for expert dispatch "
-                         "(DESIGN.md §10, §14)")
-    ap.add_argument("--comm-quant", default=None, choices=[None, "int8", "fp8"],
-                    help="wire dtype for compressed substrates")
-    ap.add_argument("--comm-chunks", type=int, default=None,
-                    help="overlapped substrates: capacity micro-chunks "
-                         "pipelined behind expert compute (actual count "
-                         "= largest divisor of the capacity <= this)")
-    ap.add_argument("--ep-inner", type=int, default=None,
-                    help="hierarchical substrate: intra-tier group size "
-                         "(must divide ep; default auto ~sqrt)")
     ap.add_argument("--mesh", default=None, help="e.g. 4,2 => (data,model)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--resume", action="store_true",
@@ -148,25 +134,14 @@ def main():
     if args.reduced:
         cfg = reduced(cfg)
     if cfg.moe is not None and (args.gd_mode or args.gd_rate is not None
-                                or args.router or args.backend or args.comm
-                                or args.comm_quant
-                                or args.comm_chunks is not None
-                                or args.ep_inner is not None):
+                                or args.router or args.backend):
         gd = cfg.moe.gating_dropout
         gd = dataclasses.replace(
             gd,
             mode=args.gd_mode if args.gd_mode else gd.mode,
             rate=args.gd_rate if args.gd_rate is not None else gd.rate)
-        comm = dataclasses.replace(
-            cfg.moe.comm,
-            substrate=args.comm or cfg.moe.comm.substrate,
-            quant=args.comm_quant or cfg.moe.comm.quant,
-            n_chunks=args.comm_chunks if args.comm_chunks is not None
-            else cfg.moe.comm.n_chunks,
-            ep_inner=args.ep_inner if args.ep_inner is not None
-            else cfg.moe.comm.ep_inner)
         moe = dataclasses.replace(
-            cfg.moe, gating_dropout=gd, comm=comm,
+            cfg.moe, gating_dropout=gd,
             router_type=args.router or cfg.moe.router_type,
             backend=args.backend or cfg.moe.backend)
         cfg = dataclasses.replace(cfg, moe=moe)

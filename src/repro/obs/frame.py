@@ -2,7 +2,7 @@
 
 The frame itself lives ON DEVICE: every train step's metric dict carries
 the per-step router-health and wire counters (built inside the MoE aux
-path — core/moe.py, comm/substrate.py — and surfaced by
+path — core/moe.py, comm/exchange.py — and surfaced by
 ``training/steps.py::total_loss`` when ``TrainConfig.metrics_frame`` is
 on). The scan-fused chunk stacks them to a leading K axis and the
 Trainer fetches them in its existing once-per-chunk ``jax.device_get``
@@ -23,8 +23,6 @@ Frame schema (per step; E = n_experts):
     comm_a2a_calls     ()    all-to-all ops this step's forward launched
     comm_bytes         ()    payload bytes entering the wire
     comm_wire_bytes    ()    per-device bytes actually on the wire
-    comm_exposed_bytes ()    wire NOT hidden behind expert compute (§14)
-    comm_hidden_bytes  ()    wire pipelined behind expert compute (§14)
 """
 from __future__ import annotations
 
@@ -37,7 +35,7 @@ __all__ = ["FRAME_KEYS", "MetricsFrame", "load_imbalance", "router_health"]
 
 FRAME_KEYS = ("expert_load", "router_entropy", "dropped_frac",
               "gate_dropped", "comm_a2a_calls", "comm_bytes",
-              "comm_wire_bytes", "comm_exposed_bytes", "comm_hidden_bytes")
+              "comm_wire_bytes")
 
 
 def load_imbalance(load: np.ndarray) -> np.ndarray:
@@ -61,8 +59,6 @@ class MetricsFrame:
     comm_a2a_calls: np.ndarray       # (K,)
     comm_bytes: np.ndarray           # (K,)
     comm_wire_bytes: np.ndarray      # (K,)
-    comm_exposed_bytes: np.ndarray   # (K,)
-    comm_hidden_bytes: np.ndarray    # (K,)
 
     @classmethod
     def from_metrics(cls, ms: Dict[str, Any]) -> Optional["MetricsFrame"]:
@@ -98,8 +94,6 @@ class MetricsFrame:
             "load_imbalance": rmean(self.load_imbalance()),
             "dropped_frac": rmean(self.dropped_frac),
             "wire_bytes_total": float(np.sum(self.comm_wire_bytes)),
-            "exposed_bytes_total": float(np.sum(self.comm_exposed_bytes)),
-            "hidden_bytes_total": float(np.sum(self.comm_hidden_bytes)),
             "a2a_calls_total": float(np.sum(self.comm_a2a_calls)),
         }
 

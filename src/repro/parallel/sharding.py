@@ -23,22 +23,6 @@ from repro.configs.base import ModelConfig
 from repro.core.moe import ParallelContext
 
 
-# Hierarchical-substrate mesh factorization (DESIGN.md §10): the ep
-# group's tier structure and the axis_index_groups for its two hops live
-# next to the rest of the partitioning rules. (Defined in comm/cost.py —
-# the analytic bytes model consumes them too — and re-exported here.)
-from repro.comm.cost import ep_tier_groups, factored_ep  # noqa: E402,F401
-
-# Two-tier physical topology descriptor (DESIGN.md §14): maps the
-# ep_inner tier onto intra-pod ICI-class links and the ep_outer tier
-# onto inter-pod DCN-class links; CommConfig.topology carries one and
-# comm/cost.py::transport_time prices the wire split against it.
-# effective_chunks is the shared capacity->micro-chunk divisor rule the
-# overlapped transport and the cost model must agree on.
-from repro.comm.cost import effective_chunks  # noqa: E402,F401
-from repro.configs.base import Topology  # noqa: E402,F401
-
-
 def _axis_size(mesh: Mesh, axis) -> int:
     if axis is None:
         return 1

@@ -19,9 +19,8 @@ the BLEU benchmarks. The contract (DESIGN.md §7):
     (models/model.py), so callers always pass logical token positions.
   * ``ParallelContext`` and the MoE backend registry (DESIGN.md §6) are
     threaded through unchanged — decoding with ``--backend pallas``
-    uses the same engine. So is the communication substrate
-    (``MoEConfig.comm``, DESIGN.md §10): routed decode moves its
-    dispatch/combine bytes over the configured wire, and the scheduler's
+    uses the same engine. Routed decode moves its dispatch/combine bytes
+    over the expert exchange (DESIGN.md §10), and the scheduler's
     ``tick_log`` feeds the ``launch/serve.py --trace`` comm accounting.
 
 Since the continuous-batching refactor (DESIGN.md §9) the engine is built

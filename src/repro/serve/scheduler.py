@@ -200,7 +200,7 @@ class ContinuousScheduler:
         self.tracer = tracer if tracer is not None else get_tracer()
         # (kind, tokens) per executed device call, in order — the comm
         # accounting feed: launch/serve.py --trace prices each tick with
-        # the substrate bytes model (comm/cost.py, DESIGN.md §10)
+        # the exchange's bytes model (comm/exchange.py, DESIGN.md §10)
         self._ticks = self.metrics.series(
             "serve/tick_log", "device calls: label=kind, value=tokens")
         # live-slot count per decode tick: the sustained-concurrency

@@ -330,15 +330,9 @@ class Trainer:
                 rec["balance"] = float(ms["balance"][j])
             if "comm_wire_bytes" in ms:
                 # per-device wire bytes this step's forward moved
-                # (in-graph substrate telemetry, DESIGN.md §10)
+                # (in-graph exchange telemetry, DESIGN.md §10)
                 rec["comm_wire_bytes"] = float(ms["comm_wire_bytes"][j])
                 rec["comm_a2a_calls"] = float(ms["comm_a2a_calls"][j])
-                # exposed vs hidden wire (DESIGN.md §14): what an
-                # overlapped substrate could NOT pipeline behind
-                # expert compute this step
-                rec["comm_exposed_bytes"] = float(
-                    ms["comm_exposed_bytes"][j])
-                rec["comm_hidden_bytes"] = float(ms["comm_hidden_bytes"][j])
             if "router_entropy" in ms:
                 # MetricsFrame router-health fields (§15): per-
                 # step entropy / load imbalance / consensus bit,

@@ -118,21 +118,16 @@ def total_loss(params, batch, cfg: ModelConfig, ctx, *, rng, decision,
         bal = aux["balance"] / nmoe
         zl = aux["router_z"] / nmoe
         loss = loss + cfg.moe.balance_coef * bal + cfg.moe.router_z_coef * zl
-        # comm_* are the substrate's in-graph transport counters
+        # comm_* are the expert exchange's in-graph counters
         # (DESIGN.md §10) summed over all MoE layers of THIS forward:
         # all-to-all ops, payload bytes, and per-device wire bytes the
         # step's forward pass moved (0 on Gate-Drop/local steps; the
-        # backward pass doubles the wire, see comm/cost.py::step_cost)
+        # backward pass doubles the wire, see comm/exchange.py::step_cost)
         metrics.update(balance=bal, router_z=zl,
                        dropped_frac=aux["dropped_frac"] / nmoe,
                        comm_a2a_calls=aux["comm_a2a_calls"],
                        comm_bytes=aux["comm_bytes"],
-                       comm_wire_bytes=aux["comm_wire_bytes"],
-                       # §14 split: wire the chunked pipeline can hide
-                       # behind expert compute vs the structurally
-                       # exposed remainder (= wire for non-overlapped)
-                       comm_exposed_bytes=aux["comm_exposed_bytes"],
-                       comm_hidden_bytes=aux["comm_hidden_bytes"])
+                       comm_wire_bytes=aux["comm_wire_bytes"])
         if frame:
             # MetricsFrame router-health fields (DESIGN.md §15): the aux
             # values are already accumulated on device; surfacing them

@@ -37,7 +37,7 @@ pytestmark = pytest.mark.lint
 
 def test_shape_bytes_quantized_wire_dtypes():
     """The seed parser priced every unknown dtype at 4 bytes — the 8-bit
-    wire dtypes the compressed substrate moves were 4x over-priced."""
+    wire dtypes were 4x over-priced."""
     assert shape_bytes("s8", (8, 16)) == 128
     assert shape_bytes("u8", (8, 16)) == 128
     assert shape_bytes("f8e4m3fn", (4, 4)) == 16
@@ -387,6 +387,6 @@ def test_lint_cli_list():
     r = subprocess.run([sys.executable, "-m", "repro.launch.lint", "--list"],
                        capture_output=True, text=True, env=env, timeout=120)
     assert r.returncode == 0, r.stdout + r.stderr
-    for needle in ("no-collectives", "moe_layer/dense", "train_chunk/dropped",
+    for needle in ("no-collectives", "moe_layer/routed", "train_chunk/dropped",
                    "scheduler/ticks"):
         assert needle in r.stdout
